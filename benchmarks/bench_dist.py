@@ -1,10 +1,16 @@
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import sys
+
+# ``--cpu-mesh`` asks for the 8-fake-device CPU rehearsal; it must be set
+# before jax initializes (jax locks the device count).  Without it the bench
+# runs on the platform JAX finds, and says which.
+if "--cpu-mesh" in sys.argv:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Distributed-layer benchmarks on 8 fake CPU devices (DESIGN.md §7).
 
-The two lines above MUST stay first: jax locks the device count on first
+The block above MUST stay first: jax locks the device count on first
 init (same contract as launch/dryrun.py).
 
 1. **Sharded vs single-device batched updates** — B stacked truncated rank-1
@@ -29,7 +35,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import emit, time_fn
@@ -37,6 +43,7 @@ from repro.api import SvdState
 from repro.core.engine import SvdEngine
 from repro.core.svd_update import TruncatedSvd
 from repro.dist.collectives import factor_wire_bytes
+from repro.launch.mesh import auto_mesh
 from repro.launch.roofline import collective_bytes
 from repro.optim.compression import (
     CompressionState,
@@ -136,7 +143,10 @@ def bench_wire(mesh) -> dict:
 
 
 def run() -> dict:
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    dev = jax.devices()
+    print(f"bench_dist: platform {dev[0].platform} ({dev[0].device_kind}) "
+          f"x{len(dev)}", flush=True)
+    mesh = auto_mesh((jax.device_count(),), ("data",))
     summary = {
         "devices": jax.device_count(),
         "sharded_updates": bench_sharded_updates(mesh),

@@ -1,11 +1,17 @@
 import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+import sys
+
+# ``--cpu-mesh`` asks for the 8-fake-device CPU rehearsal; it must be set
+# before jax initializes (jax locks the device count).  Without it the bench
+# runs on the platform JAX finds, and says which.
+if "--cpu-mesh" in sys.argv:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Fleet tier vs single service: sustained throughput + latency SLO
 (DESIGN.md §13).
 
-The two lines above MUST stay first: jax locks the device count on first
+The block above MUST stay first: jax locks the device count on first
 init (same contract as bench_dist.py) — the fleet arms run on 8 fake CPU
 devices.  Fake devices share ONE physical core, so the fleet's win here is
 NOT device parallelism: it is continuous batching's round shape.  A
@@ -45,6 +51,7 @@ from pathlib import Path
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from benchmarks.common import bench_metadata, emit, open_loop
@@ -240,6 +247,9 @@ def bench_latency(single_rate_hz: float) -> dict:
 
 
 def run() -> dict:
+    dev = jax.devices()
+    print(f"bench_fleet: platform {dev[0].platform} ({dev[0].device_kind}) "
+          f"x{len(dev)}", flush=True)
     # metrics on for every arm (uniform cost, so arm ratios are untouched):
     # per-shard serve_* gauges, fleet_* rollups and the emit() bench_us rows
     # all land in one registry the summary can count.

@@ -34,40 +34,25 @@ def _summarize_dryrun() -> None:
         print(f"{name},{us:.1f},bottleneck={dom[0]}")
 
 
+_IN_PROCESS = ("fig1_2_runtime", "fig3_error_vs_p", "table2_accuracy",
+               "table1_complexity", "framework_bench", "bench_engine",
+               "bench_serve", "bench_updates")
+
+
 def main() -> None:
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-
-    from benchmarks import (
-        bench_engine,
-        bench_serve,
-        bench_updates,
-        fig1_2_runtime,
-        fig3_error_vs_p,
-        framework_bench,
-        table1_complexity,
-        table2_accuracy,
-    )
-
-    print("name,us_per_call,derived")
-    fig1_2_runtime.run()
-    fig3_error_vs_p.run()
-    table2_accuracy.run()
-    table1_complexity.run()
-    framework_bench.run()
-    bench_engine.run()
-    bench_serve.run()
-    bench_updates.run()
-    # bench_dist and bench_fleet pin XLA_FLAGS/JAX_PLATFORMS at module
-    # import to fake an 8-device CPU mesh; jax is already initialized here,
-    # so each has to run in its own interpreter.
+    """Run every bench in a child process of its own: this parent never
+    touches JAX, so a child that needs the accelerator can take it.
+    ``--cpu-mesh`` is passed on to bench_dist/bench_fleet (their 8-fake-
+    device CPU rehearsal)."""
+    root = Path(__file__).parent.parent
+    print("name,us_per_call,derived", flush=True)
+    for name in _IN_PROCESS:
+        code = ("import jax; jax.config.update('jax_enable_x64', True); "
+                f"from benchmarks import {name}; {name}.run()")
+        subprocess.run([sys.executable, "-c", code], cwd=root, check=True)
+    extra = ["--cpu-mesh"] if "--cpu-mesh" in sys.argv[1:] else []
     for mod in ("benchmarks.bench_dist", "benchmarks.bench_fleet"):
-        subprocess.run(
-            [sys.executable, "-m", mod],
-            cwd=Path(__file__).parent.parent,
-            check=True,
-        )
+        subprocess.run([sys.executable, "-m", mod, *extra], cwd=root, check=True)
     _summarize_dryrun()
 
 

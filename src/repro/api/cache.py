@@ -35,22 +35,23 @@ def enable_compilation_cache(cache_dir: str | Path) -> Path:
     """Opt this process into the persistent XLA compilation cache at
     ``cache_dir`` (created if missing).  Idempotent; returns the directory.
 
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins over ``cache_dir``: the
+    deployment chose where compiled executables live, and the code sets no
+    other directory.
+
     Call it BEFORE the executables you want cached are built — in serving
     terms, before ``api.warmup`` / service ``restore`` replay the warmed
     geometry set.  Threaded through ``SvdService.restore(cache_dir=)`` and
     ``SvdFleet.restore(cache_dir=)`` so failover restores compile nothing
     that any previous process on this cache already compiled.
     """
-    cache_dir = Path(cache_dir)
+    cache_dir = Path(os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir)
     cache_dir.mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", str(cache_dir))
     # persist EVERY compile: the serving executables are small and the point
     # is a bitwise-observable "no new entries" zero-recompile contract
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:  # older jax: no size gate — already persists all
-        pass
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return cache_dir
 
 

@@ -126,15 +126,18 @@ class UpdatePolicy:
     # -- engine folding -----------------------------------------------------
 
     def resolve_method(self, problem_n: int, *, m: int | None = None,
-                       n: int | None = None, rank: int | None = None) -> str:
+                       n: int | None = None, rank: int | None = None,
+                       dtype=None) -> str:
         """Concrete engine method for a problem of secular size ``problem_n``
         (``n`` for full updates, ``rank + 1`` for truncated ones).
 
         ``auto`` prefers the fused megakernel whenever enough geometry is
         known (``m``, plus ``n``/``rank`` where they differ from
         ``problem_n``) and it fits the kernel's VMEM budget; otherwise it
-        falls back to the FMM-above-the-tree-floor rule.  Callers without
-        geometry get the pre-fused behavior unchanged:
+        falls back to the FMM-above-the-tree-floor rule — except for a
+        32-bit (or narrower) ``dtype``, where FMM returns NaN and ``auto``
+        stays ``direct``.  Callers without geometry get the pre-fused
+        behavior unchanged:
 
         >>> from repro.api import UpdatePolicy
         >>> UpdatePolicy(method="fmm").resolve_method(problem_n=256)
@@ -145,6 +148,8 @@ class UpdatePolicy:
         'kernel'
         >>> UpdatePolicy().resolve_method(48, m=32)  # auto + geometry: fused
         'fused'
+        >>> UpdatePolicy().resolve_method(256, dtype="float32")  # never fmm in f32
+        'direct'
         """
         if self.method == "fast":
             raise NotImplementedError(
@@ -155,20 +160,24 @@ class UpdatePolicy:
         if self.method == "pallas":
             return "kernel"
         if self.method == "auto":
+            dt = np.dtype(self.storage_dtype if self.storage_dtype is not None
+                          else dtype if dtype is not None else np.float32)
             if m is not None:
                 from repro.kernels.fused_update import fused_supported
 
-                dt = self.storage_dtype if self.storage_dtype is not None else np.float32
                 if fused_supported(m, n if n is not None else problem_n,
                                    rank, dtype=dt):
                     return "fused"
             # FMM pays off only above the tree floor; tiny problems (incl.
             # every truncated (r+1)-sized core) run the stable direct route.
+            if dtype is not None and dt.itemsize <= 4:
+                return "direct"
             return "fmm" if problem_n >= _FMM_MIN_N else "direct"
         return self.method
 
     def engine_key(self, problem_n: int, *, m: int | None = None,
-                   n: int | None = None, rank: int | None = None) -> tuple:
+                   n: int | None = None, rank: int | None = None,
+                   dtype=None) -> tuple:
         """The (method, fmm_p, sign_fix, deflate_rtol, precision,
         storage_dtype, sketch_oversample, sketch_power_iters) tuple that
         keys compiled artifacts — the policy's full numerics fold.  The
@@ -177,7 +186,7 @@ class UpdatePolicy:
         jitted ``updates.sketch`` executables (the engine body itself is
         sketch-independent)."""
         return (
-            self.resolve_method(problem_n, m=m, n=n, rank=rank),
+            self.resolve_method(problem_n, m=m, n=n, rank=rank, dtype=dtype),
             self.fmm_p,
             self.sign_fix,
             self.deflate_rtol,

@@ -44,7 +44,7 @@ _DEFAULT_POLICY = UpdatePolicy()
 
 def engine_from_key(policy: UpdatePolicy, problem_n: int, *,
                     m: int | None = None, n: int | None = None,
-                    rank: int | None = None) -> SvdEngine:
+                    rank: int | None = None, dtype=None) -> SvdEngine:
     """The ONE place a policy's ``engine_key`` unpacks into ``default_engine``
     — every layer (api, dist.merge, serve) resolves through here, so the
     shared-plan-cache invariant ("equal policies never recompile") has a
@@ -54,7 +54,8 @@ def engine_from_key(policy: UpdatePolicy, problem_n: int, *,
     schedule cache, not the engine — the rank-1 executables are
     sketch-independent, so they are dropped here."""
     (method, fmm_p, sign_fix, deflate_rtol, precision, storage_dtype,
-     _sketch_os, _sketch_pi) = policy.engine_key(problem_n, m=m, n=n, rank=rank)
+     _sketch_os, _sketch_pi) = policy.engine_key(problem_n, m=m, n=n, rank=rank,
+                                                 dtype=dtype)
     return default_engine(
         method,
         fmm_p=fmm_p,
@@ -79,9 +80,10 @@ def engine_for(policy: UpdatePolicy, state: SvdState) -> SvdEngine:
     True
     """
     if state.is_full:
-        return engine_from_key(policy, state.n, m=state.m, n=state.n)
+        return engine_from_key(policy, state.n, m=state.m, n=state.n,
+                               dtype=state.s.dtype)
     return engine_from_key(policy, state.rank + 1, m=state.m, n=state.n,
-                           rank=state.rank)
+                           rank=state.rank, dtype=state.s.dtype)
 
 
 def _apply_storage_dtype(policy: UpdatePolicy, st: SvdState, a, b):
@@ -310,5 +312,5 @@ def warmup(
     if policy.storage_dtype is not None:
         dtype = policy.storage_dtype
     eng = engine_from_key(policy, n if rank is None else rank + 1,
-                          m=m, n=n, rank=rank)
+                          m=m, n=n, rank=rank, dtype=dtype)
     return eng.warmup(batch=batch, m=m, n=n, rank=rank, k=k, dtype=dtype)

@@ -47,8 +47,8 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec
 
 from repro import obs as _obs
 from repro.core.svd_update import (
@@ -198,10 +198,10 @@ class SvdEngine:
     # -- builders -----------------------------------------------------------
 
     def _with_precision(self, fn: Callable) -> Callable:
-        """Wrap an impl so tracing runs under the configured matmul precision."""
-        if self.precision is None:
-            return fn
-        prec = self.precision
+        """Wrap an impl so tracing runs under the configured matmul precision
+        (``None``: "highest" — a TPU otherwise rounds f32 matmul operands to
+        bf16, far outside the update's f32 error budget)."""
+        prec = self.precision or "highest"
 
         def wrapped(*args):
             with jax.default_matmul_precision(prec):
@@ -295,25 +295,27 @@ class SvdEngine:
     # -- mesh-aware (shard_map) builders ------------------------------------
     # Per-shard: the same vmapped impl, batch split over one mesh axis. The
     # update is independent per batch element, so there are no collectives
-    # inside — check_rep is off because shard_map's replication checker has
+    # inside — check_vma is off because shard_map's replication checker has
     # nothing to verify here and trips on Pallas/custom_vmap internals on
     # the kernel path.
 
-    def _build_batch_shard_map(self, mesh, axis: str) -> Callable:
-        vf = jax.vmap(self._full_impl())
+    @staticmethod
+    def _shard_batch(fn: Callable, mesh, axis: str, n_in: int) -> Callable:
+        # The batch split is a placement the compiler propagates, so run it
+        # on Auto axes even when the caller's mesh is Explicit (the
+        # ``jax.make_mesh`` default): the padded tail is then sliced off the
+        # sharded result like any other array.
+        mesh = jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                                 axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         spec = PartitionSpec(axis)
-        return jax.jit(
-            shard_map(vf, mesh=mesh, in_specs=(spec,) * 5, out_specs=spec,
-                      check_rep=False)
-        )
+        return jax.jit(shard_map(jax.vmap(fn), mesh=mesh, in_specs=(spec,) * n_in,
+                                 out_specs=spec, check_vma=False))
+
+    def _build_batch_shard_map(self, mesh, axis: str) -> Callable:
+        return self._shard_batch(self._full_impl(), mesh, axis, 5)
 
     def _build_truncated_batch_shard_map(self, mesh, axis: str) -> Callable:
-        vf = jax.vmap(self._trunc_impl())
-        spec = PartitionSpec(axis)
-        return jax.jit(
-            shard_map(vf, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-                      check_rep=False)
-        )
+        return self._shard_batch(self._trunc_impl(), mesh, axis, 3)
 
     @staticmethod
     def _mesh_axis_size(mesh, axis: str) -> int:
@@ -450,11 +452,7 @@ class SvdEngine:
         key = ("shard", mesh, batch_axis) + _geometry("rank_k_batch", u, s, v, va, vb)
         ent = self._entry(
             key,
-            lambda: jax.jit(shard_map(
-                jax.vmap(self._rank_k_fn()), mesh=mesh,
-                in_specs=(PartitionSpec(batch_axis),) * 5,
-                out_specs=PartitionSpec(batch_axis), check_rep=False,
-            )),
+            lambda: self._shard_batch(self._rank_k_fn(), mesh, batch_axis, 5),
         )
         out = self._call(ent, u, s, v, va, vb)
         return jax.tree.map(lambda x: x[:b_orig], out)
@@ -486,11 +484,7 @@ class SvdEngine:
         )
         ent = self._entry(
             key,
-            lambda: jax.jit(shard_map(
-                jax.vmap(self._trunc_rank_k_fn()), mesh=mesh,
-                in_specs=(PartitionSpec(batch_axis),) * 3,
-                out_specs=PartitionSpec(batch_axis), check_rep=False,
-            )),
+            lambda: self._shard_batch(self._trunc_rank_k_fn(), mesh, batch_axis, 3),
         )
         out = self._call(ent, TruncatedSvd(u_, s_, v_), va_, vb_)
         return jax.tree.map(lambda x: x[:b_orig], out)

@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.secular_body import deflation_rtols
+
 __all__ = [
     "DeflationResult",
     "SecularRoots",
@@ -69,21 +71,6 @@ class DeflationResult(NamedTuple):
     compact: jax.Array    # (n,) int32 permutation, retained-first
 
 
-def _rep_anchored_literal(val, like: jax.Array, dtype) -> jax.Array:
-    """A literal constant whose shard_map replication tracking follows ``like``.
-
-    Under ``shard_map(check_rep=True)`` literal constants carry rep ``None``
-    ("replicated over all axes") while values derived from operands carry
-    concrete axis sets; ``lax.scan`` requires the carry rep to be *equal* on
-    input and output, so a literal initial carry spuriously trips the check
-    (jax 0.4.x scan-replication error). Selecting the same literal on a
-    ``like``-derived predicate is a no-op numerically but inherits ``like``'s
-    rep, making the scan carry rep invariant.
-    """
-    c = jnp.asarray(val, dtype)
-    return lax.select(like.reshape(-1)[0] == like.reshape(-1)[0], c, c)
-
-
 def deflate(d: jax.Array, z: jax.Array, rho: jax.Array, *, rtol: float | None = None) -> DeflationResult:
     """BNS deflation for ``D + rho z z^T`` (rho > 0, d ascending).
 
@@ -93,18 +80,21 @@ def deflate(d: jax.Array, z: jax.Array, rho: jax.Array, *, rtol: float | None = 
     """
     n = d.shape[0]
     dt = d.dtype
-    eps = jnp.finfo(dt).eps
-    if rtol is None:
-        rtol = 64.0 * float(eps)
+    gap_rtol, z_rtol = deflation_rtols(dt, rtol)
 
     znorm2 = jnp.sum(z * z)
     scale = jnp.maximum(jnp.max(jnp.abs(d)), jnp.abs(rho) * znorm2) + jnp.finfo(dt).tiny
-    tol = rtol * scale
+    tol = gap_rtol * scale
+
+    def negligible(zv):
+        if z_rtol is None:
+            return jnp.abs(rho) * zv * zv <= tol
+        return jnp.abs(rho) * jnp.abs(zv) * jnp.sqrt(znorm2) <= z_rtol * scale
 
     def step(carry, i):
         z_arr, last = carry
         zi = z_arr[i]
-        tiny_i = jnp.abs(rho) * zi * zi <= tol
+        tiny_i = negligible(zi)
         have_last = last >= 0
         lastc = jnp.maximum(last, 0)
         zl = z_arr[lastc]
@@ -115,6 +105,12 @@ def deflate(d: jax.Array, z: jax.Array, rho: jax.Array, *, rtol: float | None = 
         s = jnp.where(r > 0, -zl / safe_r, 0.0)
         offdiag = jnp.abs(c * s * gap)
         do_rot = have_last & (~tiny_i) & (offdiag <= tol) & (jnp.abs(zl) > 0)
+        if z_rtol is not None:
+            # 32-bit rule: d is left in place (no LAPACK-style diagonal
+            # update), so the rotation also drops s^2 * gap from the
+            # deflated diagonal entry — bound that too (the 64-bit rule
+            # predates it and is kept bit-for-bit)
+            do_rot = do_rot & (jnp.abs(s * s * gap) <= tol)
         c = jnp.where(do_rot, c, 1.0)
         s = jnp.where(do_rot, s, 0.0)
         z_new = jnp.where(do_rot, z_arr.at[lastc].set(0.0).at[i].set(r), z_arr)
@@ -123,11 +119,11 @@ def deflate(d: jax.Array, z: jax.Array, rho: jax.Array, *, rtol: float | None = 
         b_idx = jnp.asarray(i, jnp.int32)
         return (z_new, new_last), (a_idx, b_idx, c, s)
 
-    last0 = _rep_anchored_literal(-1, z, jnp.arange(1).dtype)  # default int dtype (x64-aware)
+    last0 = jnp.asarray(-1, jnp.arange(1).dtype)  # default int dtype (x64-aware)
     (z_merged, _), (gas, gbs, cs, ss) = lax.scan(step, (z, last0), jnp.arange(n))
 
     # deflate tiny z entries
-    keep = jnp.abs(rho) * z_merged * z_merged > tol
+    keep = ~negligible(z_merged)
     z_final = jnp.where(keep, z_merged, 0.0)
     n_keep = jnp.sum(keep).astype(jnp.int32)
 

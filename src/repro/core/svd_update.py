@@ -53,7 +53,9 @@ def _rank2_symmetric_split(beta):
     h = 0.5 * beta
     r = jnp.sqrt(h * h + 1.0)
     rho_pos = h + r
-    rho_neg = h - r
+    # det = -1, so -1/rho_pos is the same root without h - r's cancellation
+    # (2% off at beta ~ 1e3 in float32); float64 keeps its historical form
+    rho_neg = h - r if jnp.finfo(beta.dtype).bits > 32 else -1.0 / rho_pos
     n_pos = jnp.sqrt(1.0 + rho_pos * rho_pos)
     n_neg = jnp.sqrt(1.0 + rho_neg * rho_neg)
     q_pos = jnp.stack([rho_pos, 1.0]) / n_pos

@@ -12,6 +12,8 @@ merges settled states through ``dist.merge``).
 
 from __future__ import annotations
 
+import jax
+
 from repro.api import UpdatePolicy
 from repro.fleet.frontend import ContinuousBatcher
 from repro.serve.svd_service import SvdService
@@ -63,14 +65,21 @@ class FleetShard:
 
     # thin delegation — the fleet routes per stream, shards do the work
 
+    def _place(self, tree):
+        # Flush rounds run under jax.default_device(self.device), which
+        # places new arrays but never moves committed ones: arrays built on
+        # another device would pull the whole round there.  Commit every
+        # leaf to the shard's device on the way in.
+        return tree if self.device is None else jax.device_put(tree, self.device)
+
     def register(self, stream_id: str, state) -> None:
-        self.service.register(stream_id, state)
+        self.service.register(stream_id, self._place(state))
 
     def enqueue(self, stream_id: str, a, b) -> int:
-        return self.frontend.admit(stream_id, a, b)
+        return self.frontend.admit(stream_id, *self._place((a, b)))
 
     def enqueue_op(self, stream_id: str, op) -> int:
-        return self.frontend.admit_op(stream_id, op)
+        return self.frontend.admit_op(stream_id, self._place(op))
 
     def pending(self) -> int:
         return self.service.pending()

@@ -23,7 +23,21 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
-__all__ = ["secular_iterate"]
+__all__ = ["deflation_rtols", "secular_iterate"]
+
+
+def deflation_rtols(dtype, rtol: float | None = None):
+    """``(gap_rtol, z_rtol)`` of the deflation test, relative to ``scale =
+    max(|d|, rho ||z||^2)``: poles closer than ``gap_rtol * scale`` merge,
+    and a merged weight deflates when ``rho z_i^2 <= gap_rtol * scale``
+    (``z_rtol is None``) or else when ``rho |z_i| ||z|| <= z_rtol * scale``.
+    An explicit ``rtol`` (``UpdatePolicy.deflate_rtol``) sets the gap
+    tolerance.
+    """
+    eps = float(jnp.finfo(dtype).eps)
+    if jnp.dtype(dtype).itemsize >= 8:
+        return (64.0 * eps if rtol is None else rtol), None
+    return (8.0 * eps if rtol is None else rtol), 8.0 * eps
 
 
 def secular_iterate(
@@ -44,46 +58,41 @@ def secular_iterate(
     ``diff[i, j] = dc_j - anchor_i`` when ``poles_axis == 1`` (roots on the
     first axis).  ``zc2`` must already be zeroed at invalid sources.  Returns
     the per-root offset ``tau`` with ``w(anchor + tau) ~= 0``, clipped to the
-    bracket.
+    bracket.  ``zc2``/``lo``/``hi`` may instead come 2-D, already shaped to
+    broadcast against ``diff`` (``(1, N)``/``(M, 1)`` for ``poles_axis == 1``)
+    — the layout Mosaic needs; ``tau`` then keeps that 2-D shape.
     """
     dt = diff.dtype
+    flat = lo.ndim == 1
+    if flat:
+        # 1-D roots/poles: lift into the 2-D layout (poles broadcast along
+        # the roots axis, roots along the poles axis) the body runs in.
+        zc2 = jnp.expand_dims(zc2, 1 - poles_axis)
+        lo = jnp.expand_dims(lo, poles_axis)
+        hi = jnp.expand_dims(hi, poles_axis)
 
     # Bisection only ever looks at the SIGN of w, so it gets a w-only
     # evaluation; the derivative reduction (inv*inv) — ~40% of the work per
     # iteration — is computed only inside the Newton steps that use it.
-    if poles_axis == 0:
-        def _inv(tau):
-            # Unguarded reciprocal + one select: 1/0 is a trap-free inf in
-            # IEEE and the where picks 0 at exact-pole slots (deflated
-            # entries, collapsed brackets).  No grads flow through here, so
-            # the usual double-where safe-divide dance would only cost two
-            # extra tensor passes per secular iteration.
-            delta = diff - tau[None, :]
-            return jnp.where(delta == 0.0, 0.0, 1.0 / delta)
+    def _inv(tau):
+        # Unguarded reciprocal + one select: 1/0 is a trap-free inf in
+        # IEEE and the where picks 0 at exact-pole slots (deflated
+        # entries, collapsed brackets).  No grads flow through here, so
+        # the usual double-where safe-divide dance would only cost two
+        # extra tensor passes per secular iteration.
+        delta = diff - tau
+        return jnp.where(delta == 0.0, 0.0, 1.0 / delta)
 
-        def w_only(tau):
-            return 1.0 + rho * jnp.sum(zc2[:, None] * _inv(tau), axis=0)
+    def _sum(x):
+        return jnp.sum(x, axis=poles_axis, keepdims=True)
 
-        def w_of(tau):
-            inv = _inv(tau)
-            r = zc2[:, None] * inv
-            w = 1.0 + rho * jnp.sum(r, axis=0)
-            wp = rho * jnp.sum(r * inv, axis=0)
-            return w, wp
-    else:
-        def _inv(tau):
-            delta = diff - tau[:, None]
-            return jnp.where(delta == 0.0, 0.0, 1.0 / delta)
+    def w_only(tau):
+        return 1.0 + rho * _sum(zc2 * _inv(tau))
 
-        def w_only(tau):
-            return 1.0 + rho * jnp.sum(zc2[None, :] * _inv(tau), axis=1)
-
-        def w_of(tau):
-            inv = _inv(tau)
-            r = zc2[None, :] * inv
-            w = 1.0 + rho * jnp.sum(r, axis=1)
-            wp = rho * jnp.sum(r * inv, axis=1)
-            return w, wp
+    def w_of(tau):
+        inv = _inv(tau)
+        r = zc2 * inv
+        return 1.0 + rho * _sum(r), rho * _sum(r * inv)
 
     def bis_step(_, carry):
         lo_c, hi_c = carry
@@ -92,7 +101,10 @@ def secular_iterate(
         go_right = w < 0.0  # w increasing on the bracket: root above mid
         return jnp.where(go_right, mid, lo_c), jnp.where(go_right, hi_c, mid)
 
-    lo_f, hi_f = lax.fori_loop(0, n_bisect, bis_step, (lo, hi))
+    # int32 trip counts: under x64 a Python-int loop index is int64, which
+    # the TPU kernel compiler rejects
+    lo_f, hi_f = lax.fori_loop(jnp.int32(0), jnp.int32(n_bisect), bis_step,
+                               (lo, hi))
 
     # Safeguarded pole-free Newton.  The anchor is always a pole of w, so
     # roots hugging it (tau -> 0) stall plain Newton: the linear model of a
@@ -127,5 +139,6 @@ def secular_iterate(
         return lo_n, hi_n, tau_n
 
     tau0 = 0.5 * (lo_f + hi_f)
-    _, _, tau = lax.fori_loop(0, n_newton, newton_step, (lo_f, hi_f, tau0))
-    return tau
+    _, _, tau = lax.fori_loop(jnp.int32(0), jnp.int32(n_newton), newton_step,
+                              (lo_f, hi_f, tau0))
+    return jnp.squeeze(tau, poles_axis) if flat else tau

@@ -115,7 +115,8 @@ def _kernel(rows_ref, cols_ref, vals_ref, mat_ref, out_ref, *, block_e: int):
         out_ref[pl.ds(r, 1), :] += val * mat_ref[pl.ds(c, 1), :]
         return carry
 
-    jax.lax.fori_loop(0, block_e, body, 0)
+    # int32 throughout: under x64 int literals are int64, which Mosaic rejects
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(block_e), body, jnp.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("out_rows", "block_e", "interpret"))
@@ -143,12 +144,12 @@ def sparse_project_pallas(
         functools.partial(_kernel, block_e=be),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(mat.shape, lambda i: (0, 0)),
+            pl.BlockSpec(rows_p.shape, lambda i: (i * 0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(cols_p.shape, lambda i: (i * 0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(vals_p.shape, lambda i: (i * 0,), memory_space=pltpu.SMEM),
+            pl.BlockSpec(mat.shape, lambda i: (i * 0, i * 0)),
         ],
-        out_specs=pl.BlockSpec((out_rows, mat.shape[1]), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((out_rows, mat.shape[1]), lambda i: (i * 0, i * 0)),
         out_shape=jax.ShapeDtypeStruct((out_rows, mat.shape[1]), mat.dtype),
         interpret=interpret,
     )(rows_p, cols_p, vals_p, mat)
@@ -172,7 +173,8 @@ def _kernel_batched(rows_ref, cols_ref, vals_ref, mat_ref, out_ref, *,
         out_ref[0, pl.ds(r, 1), :] += val * mat_ref[0, pl.ds(c, 1), :]
         return carry
 
-    jax.lax.fori_loop(0, block_e, body, 0)
+    # int32 throughout: under x64 int literals are int64, which Mosaic rejects
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(block_e), body, jnp.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("out_rows", "block_e", "interpret"))
@@ -201,12 +203,15 @@ def sparse_project_pallas_batched(
         functools.partial(_kernel_batched, block_e=be),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, src, k), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec(rows_p.shape, lambda b, i: (b * 0, i * 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(cols_p.shape, lambda b, i: (b * 0, i * 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(vals_p.shape, lambda b, i: (b * 0, i * 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, src, k), lambda b, i: (b, i * 0, i * 0)),
         ],
-        out_specs=pl.BlockSpec((1, out_rows, k), lambda b, i: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, out_rows, k), lambda b, i: (b, i * 0, i * 0)),
         out_shape=jax.ShapeDtypeStruct((bsz, out_rows, k), mat.dtype),
         interpret=interpret,
     )(rows_p, cols_p, vals_p, mat)

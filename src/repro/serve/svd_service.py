@@ -423,10 +423,14 @@ class SvdService:
             out, self._visible = self._visible, []
             return out
 
-    def _engine_for(self, rank: int) -> SvdEngine:
+    def _engine_for(self, rank: int, m: int | None = None, n: int | None = None,
+                    dtype=None) -> SvdEngine:
+        # given the full truncated geometry, resolved as api.engine_for and
+        # api.warmup resolve it: method="auto" picks the fused kernel there
         if self.engine is not None:
             return self.engine
-        return engine_from_key(self.policy, rank + 1)
+        return engine_from_key(self.policy, rank + 1, m=m, n=n, rank=rank,
+                               dtype=dtype)
 
     def _record_warm(self, kind: str, batch, m: int, n: int, r: int, dt) -> None:
         """Track the (kind, geometry) set flushes have compiled — snapshotted
@@ -473,7 +477,7 @@ class SvdService:
             self._visible.append(ev[-1])
 
     def _apply_one(self, state: SvdState, a, b) -> SvdState:
-        eng = self._engine_for(state.rank)
+        eng = self._engine_for(state.rank, state.m, state.n, state.s.dtype)
         self._record_warm("trunc", None, state.m, state.n, state.rank, state.dtype)
         t = eng.update_truncated(TruncatedSvd(state.u, state.s, state.v), a, b)
         return SvdState(u=t.u, s=t.s, v=t.v)
@@ -988,7 +992,7 @@ class SvdService:
                 a_stack = jnp.concatenate([a_stack, jnp.zeros(pad_a, dt)])
                 b_stack = jnp.concatenate([b_stack, jnp.zeros(pad_b, dt)])
 
-            eng = self._engine_for(r)
+            eng = self._engine_for(r, m, n, dt)
             if self.policy.mesh is None:
                 kind = "trunc_batch" if k == 1 else f"trunc_scan{k}"
                 self._record_warm(kind, bsz + pad, m, n, r, dt)
@@ -1148,7 +1152,10 @@ class SvdService:
             snap.stream_ids, snap.states, snap.pending_a, snap.pending_b,
             pend_ops, orders,
         ):
-            svc._streams[sid] = SvdState(u=st.u, s=st.s, v=st.v)
+            # onto the device here (a transfer, bitwise): numpy leaves would
+            # each cost an eager convert compile at the first flush
+            u, s, v = jax.device_put((st.u, st.s, st.v))
+            svc._streams[sid] = SvdState(u=u, s=s, v=v)
             n_pairs = np.asarray(pa).shape[0]
             if order is None:
                 order = "p" * n_pairs          # v1 snapshots: all-pair FIFOs

@@ -22,6 +22,7 @@ import jax
 from jax.sharding import NamedSharding
 
 from repro.dist import sharding as sh
+from repro.launch.mesh import auto_mesh
 
 __all__ = ["plan_mesh", "plan_shard_count", "reshard", "largest_factorization"]
 
@@ -37,7 +38,7 @@ def largest_factorization(n: int, max_model: int = 16) -> tuple[int, int]:
 def plan_mesh(max_model: int = 16):
     n = jax.device_count()
     data, model = largest_factorization(n, max_model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return auto_mesh((data, model), ("data", "model"))
 
 
 def plan_shard_count(max_shards: int | None = None, *, devices=None) -> int:

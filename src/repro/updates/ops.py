@@ -23,10 +23,9 @@ Ops:
 * ``Decay(lam)`` — ``lam * A``; folds into the singular values for free
   (zero engine dispatches).
 * ``RemoveRows(idx)`` / ``RemoveCols(idx)`` — *downdates*: delete rows /
-  columns by static index.  Each deletion is the dual rank-1 perturbation
-  (Peña & Sauer, arXiv:1809.03285): zero the slice via ``A - (A e_j) e_j^T``
-  on the existing rank-1 engine, then drop the zeroed row of the factor —
-  a free geometry shrink, no LAPACK SVD anywhere.
+  columns by static index.  The planner drops the factor rows and
+  re-orthonormalizes with one tall QR + an r x r core SVD — exact, no
+  engine dispatch, no LAPACK SVD anywhere.
 * ``Window(size)`` — sliding-window convenience: keep the last ``size``
   rows (optionally decayed by ``lam``); lowers to
   ``Compose(Decay, RemoveRows(oldest...))``.
@@ -98,8 +97,7 @@ def _normalize_idx(idx, what: str) -> tuple:
     if any(i < 0 for i in out):
         raise ValueError(f"{what} indices must be non-negative; got {out}")
     if len(set(out)) != len(out):
-        # duplicates would double-subtract under the rank-1 lowering
-        # (zeroing an already-zeroed slice negates instead of removing)
+        # a duplicate index names one row twice: refuse the ambiguity
         raise ValueError(f"{what} indices must be unique; got {out}")
     return tuple(sorted(out))
 
@@ -391,11 +389,9 @@ class Decay(UpdateOp):
 @dataclasses.dataclass(frozen=True)
 class RemoveRows(UpdateOp):
     """Delete rows ``idx`` (static, unique, sorted): the downdate dual of
-    ``AppendRows``.  Lowering zeroes each row on the rank-1 engine
-    (``A - e_i (A^T e_i)^T`` — the pair is precomputable from the *original*
-    factors because zeroing row ``i`` leaves every other row untouched),
-    then drops the zeroed rows of ``u`` for free.  Carries no array data:
-    the whole op is static metadata.
+    ``AppendRows``.  Lowering drops the rows of ``u`` and re-factors (one
+    tall QR + an r x r core SVD; no engine dispatch).  Carries no array
+    data: the whole op is static metadata.
 
     >>> import numpy as np
     >>> op = RemoveRows((2, 0))
@@ -435,8 +431,8 @@ class RemoveRows(UpdateOp):
 @dataclasses.dataclass(frozen=True)
 class RemoveCols(UpdateOp):
     """Delete columns ``idx``: the downdate dual of ``AppendCols`` (the
-    ``SVD.remove_column`` algebra, batched and LAPACK-free — each deletion is
-    ``A - (A e_j) e_j^T`` on the rank-1 engine, then a free shrink of ``v``).
+    ``SVD.remove_column`` algebra, batched and LAPACK-free — drop the rows
+    of ``v``, then re-factor as ``RemoveRows`` does).
 
     >>> import numpy as np
     >>> op = RemoveCols(1)
@@ -480,8 +476,8 @@ class Window(UpdateOp):
     at the bottom, so the oldest stream entries leave first), with an
     optional forgetting factor ``lam`` on the survivors.  Lowers to
     ``Compose(Decay(lam), RemoveRows(range(m - size)))`` — a decay fold plus
-    one planned downdate per evicted row; a no-op shrink when the state
-    already fits (``m <= size``).
+    one row drop + re-factor; a no-op shrink when the state already fits
+    (``m <= size``).
 
     >>> import numpy as np
     >>> op = Window(2)

@@ -15,13 +15,12 @@ minimal *schedule* of existing ``repro.api`` calls and executes it:
   their full block rank — exact; pre-factored blocks bind directly);
 * ``Decay``      -> folded into the singular values for FREE — zero engine
   dispatches;
-* ``RemoveRows`` / ``RemoveCols`` -> one rank-1 step per deleted index that
-  zeroes the slice (``A - (A e_j) e_j^T``; the pair binds from the CURRENT
-  state's factors — zeroing one row never touches another, so a long
-  deletion list still precomputes all pairs at once and scans), then a free
-  geometry shrink dropping the zeroed factor rows;
+* ``RemoveRows`` / ``RemoveCols`` -> delete the factor rows, then one tall
+  QR + r x r SVD re-orthonormalizes (``_refactor``) — zero engine
+  dispatches, exact in float32 (rank-1 zeroing steps cancel in the squared
+  spectrum and leave ~0.1 s_max of spurious singular value there);
 * ``Window``     -> decay fold + RemoveRows of everything before the last
-  ``size`` rows (no engine dispatch when the state already fits);
+  ``size`` rows;
 * ``Compose``    -> children's schedules concatenated in order, geometry
   threaded through appends and removes.
 
@@ -48,6 +47,7 @@ from __future__ import annotations
 import threading
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -65,7 +65,8 @@ from repro.updates.ops import (
     Sparse,
     UpdateOp,
 )
-from repro.updates.sketch import sketch_svd, sparse_sketch_svd, warmup_sketch
+from repro.updates.sketch import (_small_svd, sketch_svd, sparse_sketch_svd,
+                                  warmup_sketch)
 
 __all__ = [
     "apply",
@@ -114,18 +115,15 @@ def schedule_cache_clear() -> None:
 #
 #   ("decay", path)                 s *= lam            (free)
 #   ("pad_rows", p) / ("pad_cols", p)                   (free)
-#   ("drop_rows", idx) / ("drop_cols", idx)             (free shrink)
+#   ("drop_rows", idx) / ("drop_cols", idx)             (shrink + refactor)
 #   ("rank1", path, kind, i)        one engine dispatch
 #   ("rank1_scan", path, kind, k)   k dispatches through ONE lax.scan
 #
 # ``path`` locates the source op inside Compose nesting; ``i`` names the
 # component.  Steps are static (no array data) — data binds at execution.
-# Downdate kinds (remove_rows / remove_cols / window_rows) bind their rank-1
-# pairs from the CURRENT STATE's factors, not from op array data: zeroing
-# slice j is ``A - (A e_j) e_j^T``, and since zeroing one slice leaves every
-# other one untouched, all pairs of a step run precompute from the same
-# factors — which is what lets long deletion lists lower to one scan and
-# ``apply_many`` bind a whole same-plan group in one shot.
+# Downdates (RemoveRows / RemoveCols / Window) need no rank-1 step: deleting
+# rows of ``A = U S V^T`` deletes rows of ``U``, and one QR plus an r x r SVD
+# (``_refactor``) restores an exact SVD of the smaller matrix.
 #
 # Long component runs (k >= _SCAN_MIN) lower to a single scanned step
 # (``api.update_rank_k``): trace/compile cost stays k-independent instead of
@@ -134,30 +132,6 @@ def schedule_cache_clear() -> None:
 # ---------------------------------------------------------------------------
 
 _SCAN_MIN = 17
-
-# rank-1 kinds whose (a, b) pairs bind from the current state, not op data
-_REMOVE_KINDS = ("remove_rows", "remove_cols", "window_rows")
-
-
-def _step_policy(policy: UpdatePolicy | None, step: tuple) -> UpdatePolicy | None:
-    """Engine policy for one lowered step.
-
-    Downdate steps pin the phase-chain route when the method is ``auto``:
-    zeroing a slice leaves every untouched direction's singular value exactly
-    in place, so the post-step spectrum is structurally degenerate, and the
-    fused kernel's independent left/right pole merges may pick inconsistent
-    bases inside a degenerate group (correct spectrum, wrong u/v pairing —
-    see the deflation-semantics note in ``kernels.fused_update``).  The
-    phase-chain deflation pairs pass-through columns consistently, so remove
-    kinds always lower there unless the caller forces a method explicitly.
-    """
-    if step[2] not in _REMOVE_KINDS:
-        return policy
-    if policy is None:
-        return UpdatePolicy(method="direct")
-    if policy.method == "auto":
-        return policy.replace(method="direct")
-    return policy
 
 
 def _component_steps(path: tuple, kind: str, count: int) -> list:
@@ -206,7 +180,7 @@ def _build(spec: tuple, m: int, n: int, rank: int, is_full: bool, path: tuple):
                 f"state's rank {rank} — truncate first"
             )
         drop = ("drop_rows", idx) if kind == "remove_rows" else ("drop_cols", idx)
-        return _component_steps(path, kind, len(idx)) + [drop], out
+        return [drop], out
     if kind == "window":
         if is_full:
             raise ValueError(
@@ -224,7 +198,6 @@ def _build(spec: tuple, m: int, n: int, rank: int, is_full: bool, path: tuple):
                 f"window({size}) shrinks the geometry to {out}, below the "
                 f"state's rank {rank} — truncate first"
             )
-        steps += _component_steps(path, "window_rows", cut)
         steps.append(("drop_rows", tuple(range(cut))))
         return steps, out
     if kind == "compose":
@@ -342,68 +315,11 @@ def _col(x, i: int):
     return lax.index_in_dim(x, i, axis=-1, keepdims=False)
 
 
-def _row(x, i: int):
-    """Row ``i`` off the second-to-last axis — a static slice."""
-    return lax.index_in_dim(x, i, axis=-2, keepdims=False)
-
-
-def _one_hot(cur: SvdState, dim: int, j: int):
-    """``e_j`` of length ``dim`` broadcast over ``cur``'s batch dims."""
-    z = jnp.zeros(cur.s.shape[:-1] + (dim,), cur.s.dtype)
-    return z.at[..., j].set(1.0)
-
-
-def _remove_index(src: UpdateOp, kind: str, i: int) -> int:
-    """The matrix index zeroed by component ``i`` of a downdate step."""
-    return i if kind == "window_rows" else src.idx[i]
-
-
-def _bind_remove(cur: SvdState, src: UpdateOp, kind: str, i: int):
-    """(a, b) zeroing one row/column of the CURRENT state.
-
-    Column j:  A - (A e_j) e_j^T  with  A e_j   = U (s * V[j, :]);
-    row i:     A - e_i (A^T e_i)^T with A^T e_i = V (s * U[i, :]).
-    Batch-generic: binds correctly off a stacked ``cur`` too (the
-    ``apply_many`` group path binds the whole group in one call).
-    """
-    j = _remove_index(src, kind, i)
-    if kind == "remove_cols":
-        a = -jnp.einsum("...mr,...r->...m", cur.u, cur.s * _row(cur.v, j))
-        return a, _one_hot(cur, cur.n, j)
-    b = -jnp.einsum("...nr,...r->...n", cur.v, cur.s * _row(cur.u, j))
-    return _one_hot(cur, cur.m, j), b
-
-
-def _bind_remove_block(cur: SvdState, src: UpdateOp, kind: str, count: int):
-    """All ``count`` downdate pairs at once, shaped (…, k, m)/(…, k, n) for
-    one scanned dispatch — valid because the slices being zeroed never
-    overlap, so every pair reads the same (current) factors."""
-    idx = tuple(range(count)) if kind == "window_rows" else src.idx
-    take = jnp.asarray(idx)
-    if kind == "remove_cols":
-        vj = jnp.take(cur.v, take, axis=-2)                   # (..., k, r)
-        a_blk = -jnp.einsum("...mr,...kr->...km", cur.u,
-                            cur.s[..., None, :] * vj)
-        eye = jnp.zeros((count, cur.n), cur.s.dtype)
-        eye = eye.at[jnp.arange(count), take].set(1.0)
-        b_blk = jnp.broadcast_to(eye, cur.s.shape[:-1] + (count, cur.n))
-        return a_blk, b_blk
-    uj = jnp.take(cur.u, take, axis=-2)
-    b_blk = -jnp.einsum("...nr,...kr->...kn", cur.v,
-                        cur.s[..., None, :] * uj)
-    eye = jnp.zeros((count, cur.m), cur.s.dtype)
-    eye = eye.at[jnp.arange(count), take].set(1.0)
-    a_blk = jnp.broadcast_to(eye, cur.s.shape[:-1] + (count, cur.m))
-    return a_blk, b_blk
-
-
 def _bind(cur: SvdState, op: UpdateOp, step: tuple, ctx: dict,
           policy: UpdatePolicy | None = None):
     """The (a, b) pair of one rank-1 step, shaped for the CURRENT geometry."""
     _, path, kind, i = step
     src = _resolve(op, path)
-    if kind in _REMOVE_KINDS:
-        return _bind_remove(cur, src, kind, i)
     if kind == "rank_k":
         return _col(jnp.asarray(src.u), i), _col(jnp.asarray(src.v), i)
     if kind in ("dense_delta", "sparse"):
@@ -426,8 +342,6 @@ def _bind_block(cur: SvdState, op: UpdateOp, step: tuple, ctx: dict,
     """The full (k, m)/(k, n) pair blocks of one scanned rank-k step."""
     _, path, kind, _count = step
     src = _resolve(op, path)
-    if kind in _REMOVE_KINDS:
-        return _bind_remove_block(cur, src, kind, _count)
     if kind == "rank_k":
         return (jnp.swapaxes(jnp.asarray(src.u), -1, -2),
                 jnp.swapaxes(jnp.asarray(src.v), -1, -2))
@@ -454,14 +368,35 @@ def _pad_cols(cur: SvdState, p: int) -> SvdState:
     return cur.replace(v=jnp.concatenate([cur.v, pad], axis=-2))
 
 
+def _orthonormal(x):
+    """``x``'s columns re-orthonormalized in order (QR, signs kept): exact
+    for the orthonormal ones, a completion for the arbitrary zero-s ones."""
+    q, r = jnp.linalg.qr(x)
+    d = jnp.diagonal(r, axis1=-2, axis2=-1)
+    return q * jnp.where(d < 0, -1.0, 1.0).astype(q.dtype)[..., None, :]
+
+
+def _refactor(basis, s, other):
+    """SVD of ``basis diag(s) other^T`` where ``other`` is orthonormal but
+    ``basis`` lost rows (a downdate): a tall QR plus the r x r
+    Jordan-Wielandt core SVD, exact in any precision.  ``basis`` must keep
+    at least ``r`` rows (``_build`` validates)."""
+    with jax.default_matmul_precision("highest"):
+        q, rq = jnp.linalg.qr(basis)
+        x, sig, y = _small_svd(rq * s[..., None, :])
+        return q @ _orthonormal(x), sig, other @ _orthonormal(y)
+
+
 def _drop_rows(cur: SvdState, idx: tuple) -> SvdState:
-    """Shrink the geometry by deleting (already-zeroed) rows of ``u``."""
-    return cur.replace(u=jnp.delete(cur.u, jnp.array(idx), axis=-2))
+    """Delete rows ``idx`` of the represented matrix (rows of ``u``)."""
+    u, s, v = _refactor(jnp.delete(cur.u, jnp.array(idx), axis=-2), cur.s, cur.v)
+    return cur.replace(u=u, s=s, v=v)
 
 
 def _drop_cols(cur: SvdState, idx: tuple) -> SvdState:
-    """Shrink the geometry by deleting (already-zeroed) rows of ``v``."""
-    return cur.replace(v=jnp.delete(cur.v, jnp.array(idx), axis=-2))
+    """Delete columns ``idx`` of the represented matrix (rows of ``v``)."""
+    v, s, u = _refactor(jnp.delete(cur.v, jnp.array(idx), axis=-2), cur.s, cur.u)
+    return cur.replace(u=u, s=s, v=v)
 
 
 def _exec_free(cur: SvdState, op: UpdateOp, step: tuple) -> SvdState:
@@ -504,10 +439,10 @@ def apply(state, op: UpdateOp, policy: UpdatePolicy | None = None) -> SvdState:
     for step in plan:
         if step[0] == "rank1":
             a, b = _bind(st, op, step, ctx, policy)
-            st = update(st, a, b, _step_policy(policy, step))
+            st = update(st, a, b, policy)
         elif step[0] == "rank1_scan":
             va, vb = _bind_block(st, op, step, ctx, policy)
-            st = update_rank_k(st, va, vb, _step_policy(policy, step))
+            st = update_rank_k(st, va, vb, policy)
         else:
             st = _exec_free(st, op, step)
     return st
@@ -574,35 +509,23 @@ def apply_many(
         )
         for step in plan:
             if step[0] == "rank1":
-                if step[2] in _REMOVE_KINDS:
-                    # downdate pairs bind from the STATE, not from op data;
-                    # the plan embeds the indices (spec ⊂ plan key), so every
-                    # group member shares them and ONE batch-generic bind off
-                    # the stacked state yields the whole (B, ·) pair —
-                    # per-member binds against ``cur`` would read B-fold data
-                    a, b = _bind(cur, group_ops[0], step, ctxs[0], policy)
-                else:
-                    # _bind only reads the (shared) geometry off ``cur``, so
-                    # the stacked state binds each member's unbatched vectors
-                    pairs = [
-                        _bind(cur, op, step, ctx, policy)
-                        for op, ctx in zip(group_ops, ctxs)
-                    ]
-                    a = jnp.stack([p[0] for p in pairs])
-                    b = jnp.stack([p[1] for p in pairs])
-                cur = update(cur, a, b, _step_policy(policy, step))
+                # _bind only reads the (shared) geometry off ``cur``, so
+                # the stacked state binds each member's unbatched vectors
+                pairs = [
+                    _bind(cur, op, step, ctx, policy)
+                    for op, ctx in zip(group_ops, ctxs)
+                ]
+                a = jnp.stack([p[0] for p in pairs])
+                b = jnp.stack([p[1] for p in pairs])
+                cur = update(cur, a, b, policy)
             elif step[0] == "rank1_scan":
-                if step[2] in _REMOVE_KINDS:
-                    va, vb = _bind_block(cur, group_ops[0], step, ctxs[0],
-                                         policy)
-                else:
-                    blocks = [
-                        _bind_block(cur, op, step, ctx, policy)
-                        for op, ctx in zip(group_ops, ctxs)
-                    ]
-                    va = jnp.stack([p[0] for p in blocks])
-                    vb = jnp.stack([p[1] for p in blocks])
-                cur = update_rank_k(cur, va, vb, _step_policy(policy, step))
+                blocks = [
+                    _bind_block(cur, op, step, ctx, policy)
+                    for op, ctx in zip(group_ops, ctxs)
+                ]
+                va = jnp.stack([p[0] for p in blocks])
+                vb = jnp.stack([p[1] for p in blocks])
+                cur = update_rank_k(cur, va, vb, policy)
             elif step[0] == "decay":
                 lams = jnp.stack(
                     [jnp.asarray(_resolve(op, step[1]).lam) for op in group_ops]
@@ -691,9 +614,7 @@ def warmup_plan(
             cur_n -= len(step[1])
         elif step[0] in ("rank1", "rank1_scan"):
             k = step[3] if step[0] == "rank1_scan" else None
-            # remove steps execute under the step-pinned policy (see
-            # _step_policy) — warm the route they will actually dispatch
-            entries.setdefault((cur_m, cur_n, k), _step_policy(policy, step))
+            entries.setdefault((cur_m, cur_n, k), policy)
             if (cur_m, cur_n) not in geoms:
                 geoms.append((cur_m, cur_n))
     for (gm, gn, k), pol in entries.items():

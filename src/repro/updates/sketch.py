@@ -85,6 +85,17 @@ _SEED = 0
 _SEED_CORANGE = 1
 
 
+def _highest(fn):
+    """Trace ``fn`` with "highest" matmul precision: a TPU otherwise rounds
+    f32 einsum/QR operands to bf16, and the sketch must be exact whenever
+    ``l >= rank(delta)``."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kw):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kw)
+    return wrapped
+
+
 def sample_count(k: int, oversample: int, m: int, n: int) -> int:
     """Sample columns l = min(k + oversample, m, n) the range-finder draws.
 
@@ -154,6 +165,7 @@ def _topk(u, s, v, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
+@_highest
 def factored_svd(q, b, k: int):
     """Top-k triplets of the already-factored product ``q @ b`` — for
     callers that hold a low-rank factorization (``optim.compression``'s
@@ -173,6 +185,7 @@ def factored_svd(q, b, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "oversample", "power_iters"))
+@_highest
 def range_finder(delta, k: int, *, oversample: int = 8, power_iters: int = 1):
     """The QB decomposition ``delta ≈ q @ b`` (Halko stage A + sketch).
 
@@ -202,6 +215,7 @@ def range_finder(delta, k: int, *, oversample: int = 8, power_iters: int = 1):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "oversample", "power_iters"))
+@_highest
 def sketch_svd(delta, k: int, *, oversample: int = 8, power_iters: int = 1):
     """Top-k SVD triplets ``(u, s, v)`` of ``delta`` via the range-finder —
     the replacement for every dense ``jnp.linalg.svd`` sketch call site
@@ -225,6 +239,7 @@ def sketch_svd(delta, k: int, *, oversample: int = 8, power_iters: int = 1):
 
 
 @functools.partial(jax.jit, static_argnames=("m", "n", "k", "oversample"))
+@_highest
 def sparse_sketch_svd(rows, cols, vals, *, m: int, n: int, k: int,
                       oversample: int = 8):
     """Top-k triplets of the static-nnz COO delta ``S[rows[e], cols[e]] +=

@@ -248,3 +248,27 @@ def test_warmup_precompiles_policy_geometry():
     eng = api.engine_for(pol, st)
     api.update(st, jnp.zeros((4, 8)), jnp.zeros((4, 10)), pol)
     assert eng.cache_info().hits >= 1
+
+
+def test_enable_compilation_cache_defers_to_env(tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins over the caller's directory; the
+    caller's is used only when the variable is unset."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    env_dir, arg_dir = tmp_path / "env", tmp_path / "arg"
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in names}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
+        assert api.enable_compilation_cache(arg_dir) == env_dir
+        assert jax.config.jax_compilation_cache_dir == str(env_dir)
+        assert env_dir.is_dir() and not arg_dir.exists()
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert api.enable_compilation_cache(arg_dir) == arg_dir
+        assert jax.config.jax_compilation_cache_dir == str(arg_dir)
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

@@ -88,7 +88,7 @@ def test_compressed_allreduce_under_shard_map():
         import json
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.optim.compression import (CompressionState, compression_init,
                                              compress_decompress)
         from repro.api import SvdState
@@ -117,7 +117,7 @@ def test_compressed_allreduce_under_shard_map():
                        out_specs=(P("data"), out_state_specs))
         g_hat, st = jax.jit(fn)(g_all, state)
         dense_mean = np.mean(np.asarray(g_all), axis=0)
-        got = np.asarray(g_hat[0])  # pmean'd: every shard holds the mean
+        got = np.asarray(g_hat)[0]  # pmean'd: every shard holds the mean
         rel = float(np.linalg.norm(got - dense_mean) / np.linalg.norm(dense_mean))
         print(json.dumps({"rel": rel, "err_shape": list(st.error.shape)}))
     """)
@@ -174,7 +174,7 @@ def test_distributed_merge_and_basis_agreement():
         jax.config.update("jax_enable_x64", True)  # suite-wide numerics default
         import jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core.svd_update import TruncatedSvd
         from repro.dist.merge import distributed_merge
 
@@ -198,7 +198,7 @@ def test_distributed_merge_and_basis_agreement():
         fn = shard_map(body, mesh=mesh,
                        in_specs=(TruncatedSvd(P("data"), P("data"), P("data")),),
                        out_specs=TruncatedSvd(P(), P(), P()),
-                       check_rep=False)
+                       check_vma=False)
         merged = jax.jit(fn)(local)
         rec = (np.asarray(merged.u) * np.asarray(merged.s)) @ np.asarray(merged.v).T
         uu, sv, vt = np.linalg.svd(M)
@@ -226,7 +226,7 @@ def test_distributed_merge_and_basis_agreement():
                                       tracker=TruncatedSvd(P("data"), P("data"), P("data")))
         agreed = jax.jit(shard_map(agree_body, mesh=mesh,
                                    in_specs=(per_worker,), out_specs=per_worker,
-                                   check_rep=False))(states)
+                                   check_vma=False))(states)
         # consensus: every worker holds the same v_basis (merged right basis)
         vb = np.asarray(agreed.v_basis)
         v_spread = float(np.abs(vb - vb[0]).max())
@@ -235,8 +235,8 @@ def test_distributed_merge_and_basis_agreement():
         orth = max(float(np.abs(tu[w].T @ tu[w] - np.eye(r)).max()) for w in range(8))
         # each tracker reconstructs its own row block of the global rank-r SVD
         block = max(
-            float(np.abs((tu[w] * np.asarray(agreed.tracker.s[w]))
-                         @ np.asarray(agreed.tracker.v[w]).T
+            float(np.abs((tu[w] * np.asarray(agreed.tracker.s)[w])
+                         @ np.asarray(agreed.tracker.v)[w].T
                          - opt[w*m:(w+1)*m]).max())
             for w in range(8)
         )

@@ -140,15 +140,11 @@ def test_remove_specs_hashable_json_and_skeletons():
 
 def test_remove_lowering_steps():
     st = _roomy_state(8, 6, 2, 3)
+    # deletes are a factor-row drop + refactor: zero engine dispatches
     plan = lower(RemoveRows((1, 5)), st)
-    assert plan == (("rank1", (), "remove_rows", 0),
-                    ("rank1", (), "remove_rows", 1),
-                    ("drop_rows", (1, 5)))
+    assert plan == (("drop_rows", (1, 5)),)
     plan = lower(Window(6, lam=0.9), st)
-    assert plan == (("decay", ()),
-                    ("rank1", (), "window_rows", 0),
-                    ("rank1", (), "window_rows", 1),
-                    ("drop_rows", (0, 1)))
+    assert plan == (("decay", ()), ("drop_rows", (0, 1)))
     # fits already: decay fold only, zero engine dispatches
     assert lower(Window(8), st) == (("decay", ()),)
 
@@ -157,8 +153,7 @@ def test_remove_long_runs_lower_to_one_scan():
     st = _roomy_state(_SCAN_MIN + 8, 6, 2, 3)
     idx = tuple(range(_SCAN_MIN))
     plan = lower(RemoveRows(idx), st)
-    assert plan == (("rank1_scan", (), "remove_rows", _SCAN_MIN),
-                    ("drop_rows", idx))
+    assert plan == (("drop_rows", idx),)
 
 
 def test_remove_requires_truncated_state():
@@ -373,7 +368,7 @@ def test_apply_many_mixed_shrinking_and_preserving_groups():
 
 
 def test_apply_many_batched_scan_group():
-    """Long deletion lists group-batch through ONE scanned dispatch."""
+    """Long deletion lists group-batch (one stacked drop + refactor)."""
     rng = np.random.default_rng(15)
     m = _SCAN_MIN + 6
     sts = [_roomy_state(m, 6, 2, 3, rng) for _ in range(3)]
@@ -394,8 +389,8 @@ def test_warmup_plan_tracks_shrinking_geometries():
     pol = UpdatePolicy()
     op = Compose((RemoveRows((0, 1)), RemoveCols(0)))
     geoms = warmup_plan(pol, op, m=8, n=6, rank=3)
-    # remove steps dispatch at the PRE-drop geometry of each stage
-    assert geoms == [(8, 6), (6, 6)]
+    # deletes dispatch no engine step: nothing to warm
+    assert geoms == []
 
 
 # ---------------------------------------------------------------------------

@@ -147,7 +147,7 @@ def test_fused_truncated_matches_direct():
 def test_pallas_interpret_matches_body_full():
     m, n = 6, 9
     u, s, v, a, b = _problem(m, n)
-    ref = F._fused_body(u, s, v, a, b)
+    ref = F.fused_update_xla(u, s, v, a, b)
     out = F.fused_update_pallas(u, s, v, a, b, interpret=True)
     for got, want, name in zip(out, ref, ("u", "s", "v", "dl", "dr")):
         got = got[:, :m] if name == "v" else got
@@ -163,7 +163,7 @@ def test_pallas_interpret_matches_body_truncated():
     s = jnp.asarray(np.sort(np.abs(RNG.normal(size=r)))[::-1].copy())
     a = jnp.asarray(RNG.normal(size=m))
     b = jnp.asarray(RNG.normal(size=n))
-    ref = F._fused_truncated_body(u, s, v, a, b)
+    ref = F.fused_update_truncated_xla(u, s, v, a, b)
     out = F.fused_update_truncated_pallas(u, s, v, a, b, interpret=True)
     for got, want in zip(out, ref):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-12)
@@ -178,7 +178,7 @@ def test_pallas_interpret_batched_matches_items():
     u, s, v, a, bb = (jnp.stack(c) for c in cols)
     out = F.fused_update_pallas_batched(u, s, v, a, bb, interpret=True)
     for i in range(b_sz):
-        ref = F._fused_body(u[i], s[i], v[i], a[i], bb[i])
+        ref = F.fused_update_xla(u[i], s[i], v[i], a[i], bb[i])
         np.testing.assert_allclose(np.asarray(out[0][i]), np.asarray(ref[0]),
                                    atol=1e-12)
         np.testing.assert_allclose(np.asarray(out[1][i]), np.asarray(ref[1]),
