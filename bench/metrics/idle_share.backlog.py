@@ -1,0 +1,7 @@
+"""Per-layer metric ``idle_share.backlog``: see ``bench.readers.idle_share``."""
+
+from bench import readers
+
+
+def read(run):
+    return readers.idle_share(run)
