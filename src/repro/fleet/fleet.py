@@ -37,6 +37,7 @@ under the new spec before services are rebuilt — the elastic path
 from __future__ import annotations
 
 import dataclasses
+import time
 from functools import partial
 
 import jax
@@ -254,8 +255,12 @@ class SvdFleet:
     def enqueue(self, stream_id: str, a, b) -> tuple[int, int]:
         """Route + admit one rank-1 event; returns its fleet-level
         visibility token ``(shard, token)`` (see ``poll``)."""
+        t0 = time.perf_counter_ns() if _obs.enabled() else None
         sh = self.shard_of(stream_id)
-        return (sh, self.shards[sh].enqueue(stream_id, a, b))
+        token = self.shards[sh].enqueue(stream_id, a, b)
+        if t0 is not None:
+            self.shards[sh].count_enqueue(time.perf_counter_ns() - t0)
+        return (sh, token)
 
     def enqueue_op(self, stream_id: str, op) -> tuple[int, int]:
         sh = self.shard_of(stream_id)

@@ -138,7 +138,10 @@ class ContinuousBatcher:
         continuous-batching admission the module doc describes).  This is
         the event-loop tick — callers with their own loop (the fleet, the
         benchmark driver) call it between arrivals."""
-        if not self.continuous or not self.service.pending():
+        # no span for a tick that can seal nothing: an open loop ticks far
+        # more often than it seals, and such spans would fill the buffer
+        if (not self.continuous or not self.service.pending()
+                or not self.service.has_capacity()):
             return 0
         dispatched = 0
         with _obs.span("pump", **self.service._obs_labels) as sp:

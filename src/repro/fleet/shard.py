@@ -12,8 +12,11 @@ merges settled states through ``dist.merge``).
 
 from __future__ import annotations
 
+import time
+
 import jax
 
+from repro import obs as _obs
 from repro.api import UpdatePolicy
 from repro.fleet.frontend import ContinuousBatcher
 from repro.serve.svd_service import SvdService
@@ -55,6 +58,7 @@ class FleetShard:
         # health_* probe this shard publishes carries shard=<index>, and
         # registry().aggregate(...) rolls them into fleet totals
         self.service._obs_labels = {"shard": str(index)}
+        self._timers: tuple | None = None   # cached (registry key, counters)
         self.frontend = ContinuousBatcher(
             self.service,
             max_depth=max_depth,
@@ -76,7 +80,29 @@ class FleetShard:
         self.service.register(stream_id, self._place(state))
 
     def enqueue(self, stream_id: str, a, b) -> int:
-        return self.frontend.admit(stream_id, *self._place((a, b)))
+        t0 = time.perf_counter_ns() if _obs.enabled() else None
+        a, b = self._place((a, b))
+        if t0 is not None:
+            self._timer_counters()[1].inc(time.perf_counter_ns() - t0)
+        return self.frontend.admit(stream_id, a, b)
+
+    def _timer_counters(self) -> tuple:
+        """``(enqueue_host_ns, place_host_ns, enqueue_timed)`` of this shard,
+        cached until the registry is swapped or reset."""
+        reg = _obs.registry()
+        key = (reg, reg.generation)
+        if self._timers is None or self._timers[0] != key:
+            labels = self.service._obs_labels
+            self._timers = (key, tuple(
+                reg.counter(name, **labels)
+                for name in ("enqueue_host_ns", "place_host_ns", "enqueue_timed")))
+        return self._timers[1]
+
+    def count_enqueue(self, host_ns: int) -> None:
+        """Record one timed enqueue (route, place, admit) of this shard."""
+        enqueue_ns, _, timed = self._timer_counters()
+        enqueue_ns.inc(host_ns)
+        timed.inc()
 
     def enqueue_op(self, stream_id: str, op) -> int:
         return self.frontend.admit_op(stream_id, self._place(op))

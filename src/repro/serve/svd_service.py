@@ -75,6 +75,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 import warnings
 from collections import OrderedDict, deque
 from dataclasses import dataclass
@@ -388,10 +389,14 @@ class SvdService:
         # ("pair", a, b, token) | ("op", UpdateOp, token)
         self._pending: dict[str, deque] = {}
         self._eff_shape: dict[str, tuple] = {}   # post-queue (m, n) per stream
-        # per dispatched round: (device outputs, tokens the round carried)
-        self._in_flight: deque[tuple[list, list]] = deque()
+        # per dispatched round: (device outputs, tokens it carried, round number)
+        self._in_flight: deque[tuple[list, list, int]] = deque()
         self._warmed: set[tuple] = set()         # (kind, batch, m, n, r, dtype)
         self._next_token = 0                     # visibility tokens (runtime-only)
+        self._next_round = 0                     # round numbers (runtime-only)
+        # token -> perf_counter_ns at enqueue, filled only while obs is on;
+        # a stamp leaves with its event (sealed, evicted, settled, replaced)
+        self._enqueued_ns: dict[int, int] = {}
         self._visible: list[int] = []            # retired tokens, FIFO, undrained
         self._lock = threading.RLock()
         # observability (repro.obs, DESIGN.md §15): the fleet tier grafts
@@ -450,6 +455,9 @@ class SvdService:
         with self._lock:
             st = as_state(state)
             self._streams[stream_id] = SvdState(u=st.u, s=st.s, v=st.v)
+            if self._enqueued_ns:
+                for ev in self._pending.get(stream_id, ()):
+                    self._enqueued_ns.pop(ev[-1], None)
             self._pending[stream_id] = deque()
             self._eff_shape[stream_id] = (st.m, st.n)
 
@@ -475,6 +483,7 @@ class SvdService:
         Sparse pair whose op token rides the LAST expanded pair)."""
         if ev[-1] is not None:
             self._visible.append(ev[-1])
+            self._enqueued_ns.pop(ev[-1], None)
 
     def _apply_one(self, state: SvdState, a, b) -> SvdState:
         eng = self._engine_for(state.rank, state.m, state.n, state.s.dtype)
@@ -629,6 +638,8 @@ class SvdService:
                     f"{stream_id!r} geometry ({m},)/({n},)"
                 )
             token = self._issue_token()
+            if _obs.enabled():
+                self._enqueued_ns[token] = time.perf_counter_ns()
             self._pending[stream_id].append(("pair", a, b, token))
             self.stats.enqueued += 1
             self._maybe_autoflush()
@@ -660,6 +671,9 @@ class SvdService:
             m, n = self._effective_shape(stream_id)
             events, out_shape = self._lower_op_events(op, m, n, stream_id)
             events = [ev + (self._issue_token(),) for ev in events]
+            if _obs.enabled():
+                now = time.perf_counter_ns()
+                self._enqueued_ns.update((ev[-1], now) for ev in events)
             self._pending[stream_id].extend(events)
             self._eff_shape[stream_id] = out_shape
             self.stats.enqueued += len(events)
@@ -806,8 +820,8 @@ class SvdService:
             self._visible.extend(self._in_flight.popleft()[1])
 
     def _retire_oldest(self) -> None:
-        outputs, tokens = self._in_flight.popleft()
-        with _obs.span("reap", outputs=len(outputs)):
+        outputs, tokens, round_no = self._in_flight.popleft()
+        with _obs.span("reap", outputs=len(outputs), round=round_no):
             jax.block_until_ready(outputs)
         self._visible.extend(tokens)
 
@@ -825,6 +839,20 @@ class SvdService:
                 for f in dataclasses.fields(SvdServiceStats)])
         for name, gauge in self._stat_gauges[1]:
             gauge.set(getattr(self.stats, name))
+
+    def _observe_waits(self, tokens, t_ns: int) -> None:
+        """Pop the enqueue stamps of sealed tokens into the ``queue_wait_us``
+        histogram: enqueue to the dispatch of the round that took them."""
+        stamps = self._enqueued_ns
+        if not _obs.enabled():
+            for tok in tokens:
+                stamps.pop(tok, None)
+            return
+        hist = _obs.registry().histogram("queue_wait_us", **self._obs_labels)
+        for tok in tokens:
+            t0 = stamps.pop(tok, None)
+            if t0 is not None:
+                hist.observe((t_ns - t0) / 1e3)
 
     def _health_monitor(self) -> "_obs.HealthMonitor":
         if self._health is None:
@@ -878,16 +906,21 @@ class SvdService:
         live_ids = [sid for sid, q in self._pending.items() if q]
         if not live_ids:
             return 0
+        round_no = self._next_round
+        self._next_round += 1
         with _obs.span("flush_round", streams=len(live_ids),
-                       max_depth=max_depth):
-            applied = self._flush_round_impl(live_ids, max_depth)
+                       max_depth=max_depth, round=round_no):
+            applied = self._flush_round_impl(live_ids, max_depth, round_no)
         if _obs.enabled():
             self._publish_stats()
         return applied
 
-    def _flush_round_impl(self, live_ids: list, max_depth: int) -> int:
+    def _flush_round_impl(self, live_ids: list, max_depth: int, round_no: int) -> int:
         # Backpressure: bound how far the host can run ahead of the device.
         self._reap_ready()
+        # nothing older in flight: the device finished its work and waited
+        # for this round on the host
+        starved = not self._in_flight
         while self.max_in_flight > 0 and len(self._in_flight) >= self.max_in_flight:
             self._retire_oldest()
             self.stats.backpressure_waits += 1
@@ -917,6 +950,8 @@ class SvdService:
                 ev = self._pending[sid].popleft()
                 if ev[-1] is not None:
                     round_tokens.append(ev[-1])
+                    if self._enqueued_ns:
+                        self._observe_waits(ev[-1:], time.perf_counter_ns())
                 round_outputs.extend(jax.tree.leaves(self._streams[sid]))
                 ops_applied += 1
             else:
@@ -954,43 +989,43 @@ class SvdService:
             # peek, don't pop: if the engine call raises (first-compile OOM,
             # backend error), the pairs stay queued and a retry re-applies
             # them — flush stays failure-atomic per group
-            pairs = [
-                [(q[j][1], q[j][2]) for j in range(k)]
-                for q in (self._pending[sid] for sid in sids)
-            ]
-            states = [self._streams[sid] for sid in sids]
             bsz = len(sids)
             pad = 0
             if self.pad_to_bucket:
                 # a group can exceed max_batch (retry after a failed flush
                 # accumulates streams) — never pad negative, just dispatch big
                 pad = max(0, _bucket(bsz, self.max_batch) - bsz)
-
-            t_stack = stack_trees(
-                [TruncatedSvd(s.u, s.s, s.v) for s in states]
-            )
-            if k == 1:
-                a_stack = jnp.stack([jnp.asarray(col[0][0], dt) for col in pairs])
-                b_stack = jnp.stack([jnp.asarray(col[0][1], dt) for col in pairs])
-                pad_a, pad_b = (pad, m), (pad, n)
-            else:
-                a_stack = jnp.stack([
-                    jnp.stack([jnp.asarray(a, dt) for a, _ in col]) for col in pairs
-                ])
-                b_stack = jnp.stack([
-                    jnp.stack([jnp.asarray(b, dt) for _, b in col]) for col in pairs
-                ])
-                pad_a, pad_b = (pad, k, m), (pad, k, n)
-            if pad:
-                # no-op rank-1 pairs (a = b = 0) along the BATCH axis only;
-                # padded outputs are discarded (scan columns are never padded
-                # — their outputs are kept, see _depth_bucket)
-                t_stack = jax.tree.map(
-                    lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)]),
-                    t_stack,
+            with _obs.span("assemble", batch=bsz + pad, depth=k):
+                pairs = [
+                    [(q[j][1], q[j][2]) for j in range(k)]
+                    for q in (self._pending[sid] for sid in sids)
+                ]
+                states = [self._streams[sid] for sid in sids]
+                t_stack = stack_trees(
+                    [TruncatedSvd(s.u, s.s, s.v) for s in states]
                 )
-                a_stack = jnp.concatenate([a_stack, jnp.zeros(pad_a, dt)])
-                b_stack = jnp.concatenate([b_stack, jnp.zeros(pad_b, dt)])
+                if k == 1:
+                    a_stack = jnp.stack([jnp.asarray(col[0][0], dt) for col in pairs])
+                    b_stack = jnp.stack([jnp.asarray(col[0][1], dt) for col in pairs])
+                    pad_a, pad_b = (pad, m), (pad, n)
+                else:
+                    a_stack = jnp.stack([
+                        jnp.stack([jnp.asarray(a, dt) for a, _ in col]) for col in pairs
+                    ])
+                    b_stack = jnp.stack([
+                        jnp.stack([jnp.asarray(b, dt) for _, b in col]) for col in pairs
+                    ])
+                    pad_a, pad_b = (pad, k, m), (pad, k, n)
+                if pad:
+                    # no-op rank-1 pairs (a = b = 0) along the BATCH axis
+                    # only; padded outputs are discarded (scan columns are
+                    # never padded — their outputs are kept, see _depth_bucket)
+                    t_stack = jax.tree.map(
+                        lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)]),
+                        t_stack,
+                    )
+                    a_stack = jnp.concatenate([a_stack, jnp.zeros(pad_a, dt)])
+                    b_stack = jnp.concatenate([b_stack, jnp.zeros(pad_b, dt)])
 
             eng = self._engine_for(r, m, n, dt)
             if self.policy.mesh is None:
@@ -1010,17 +1045,22 @@ class SvdService:
                     )
                     self.stats.scan_rounds += 1
                     self.stats.max_depth = max(self.stats.max_depth, k)
+            dispatched_ns = time.perf_counter_ns() if self._enqueued_ns else 0
             if sample_due and probe_args is None and k == 1:
                 st1 = unstack_tree(out, 0)
                 probe_args = (states[0].u, states[0].s, states[0].v,
                               a_stack[0], b_stack[0], st1.u, st1.s, st1.v)
-            for j, sid in enumerate(sids):
-                t = unstack_tree(out, j)
-                self._streams[sid] = SvdState(u=t.u, s=t.s, v=t.v)
-                for _ in range(k):
-                    ev = self._pending[sid].popleft()
-                    if ev[-1] is not None:
-                        round_tokens.append(ev[-1])
+            with _obs.span("writeback", streams=bsz):
+                first = len(round_tokens)
+                for j, sid in enumerate(sids):
+                    t = unstack_tree(out, j)
+                    self._streams[sid] = SvdState(u=t.u, s=t.s, v=t.v)
+                    for _ in range(k):
+                        ev = self._pending[sid].popleft()
+                        if ev[-1] is not None:
+                            round_tokens.append(ev[-1])
+                if self._enqueued_ns:
+                    self._observe_waits(round_tokens[first:], dispatched_ns)
             round_outputs.extend(jax.tree.leaves(out))
             applied += bsz * k
             self.stats.rounds += 1
@@ -1030,10 +1070,16 @@ class SvdService:
             jax.block_until_ready(round_outputs)       # synchronous mode
             self._visible.extend(round_tokens)
         else:
-            self._in_flight.append((round_outputs, round_tokens))
+            self._in_flight.append((round_outputs, round_tokens, round_no))
             self.stats.in_flight_peak = max(
                 self.stats.in_flight_peak, len(self._in_flight)
             )
+            if _obs.enabled():
+                # made on every round, so a window with none starved reads 0
+                starved_rounds = _obs.registry().counter(
+                    "starved_rounds", **self._obs_labels)
+                if starved:
+                    starved_rounds.inc()
         self.stats.flushes += 1
         self.stats.applied += applied
         if probe_args is not None:

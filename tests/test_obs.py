@@ -11,7 +11,11 @@ Pinned here:
   FleetSnapshot (v8) through the aux JSON round trip;
 * engine/planner cache counters mirror the public cache_info() numbers;
 * the health watchdog warns (HealthWarning) on a drifted state and counts
-  the trip.
+  the trip;
+* the host path's spans and counters: a round's spans reach a
+  ``jax.profiler`` trace nested as recorded, ``id``/``parent``/``round``
+  links, the dropped-span count, starved rounds, queue waits, and the
+  fleet's enqueue timers.
 """
 
 from __future__ import annotations
@@ -192,7 +196,11 @@ def test_chrome_trace_shape():
     for e in by_name.values():
         assert e["ph"] == "X"
         assert e["dur"] >= 0.0
-    assert by_name["inner"]["args"] == {"batch": 4}
+    # spans link to the live span they opened in; the outermost has none
+    outer_id = by_name["outer"]["args"]["id"]
+    assert by_name["outer"]["args"] == {"depth": 2, "id": outer_id}
+    assert by_name["inner"]["args"] == {"batch": 4, "id": outer_id + 1,
+                                        "parent": outer_id}
     # inner nests inside outer on the monotonic clock
     assert by_name["outer"]["ts"] <= by_name["inner"]["ts"]
     assert (by_name["inner"]["ts"] + by_name["inner"]["dur"]
@@ -423,3 +431,190 @@ def test_health_every_cadence():
     # samples on every 3rd flush tick
     assert [mon.due() for _ in range(7)] == [
         False, False, True, False, False, True, False]
+
+
+# ---------------------------------------------------------------------------
+# the host path: round spans, links, counters
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def rounds_stay_in_flight(monkeypatch):
+    """Dispatched rounds never read as finished, so they leave the
+    in-flight buffer only through ``_retire_oldest`` (the ``reap`` span),
+    however fast the CPU backend computes them."""
+    from repro.serve import svd_service
+
+    monkeypatch.setattr(svd_service, "_is_ready", lambda x: False)
+
+
+def _two_stream_service(**kw):
+    svc = SvdService(max_batch=4, policy=UpdatePolicy(method="direct"), **kw)
+    for sid in ("s0", "s1"):
+        svc.register(sid, _state())
+    return svc
+
+
+def _enqueue_both(svc):
+    for sid in ("s0", "s1"):
+        svc.enqueue(sid, *_event())
+
+
+def test_round_spans_reach_the_profiler_nested(tmp_path):
+    from jax.profiler import ProfileData
+
+    svc = _two_stream_service()
+    _enqueue_both(svc)
+    obs.start_tracing()
+    with jax.profiler.trace(str(tmp_path)):
+        svc.flush_round()
+        svc.drain()
+    obs.stop_tracing()
+
+    found = sorted(tmp_path.rglob("*.xplane.pb"))
+    assert found
+    spans = {}
+    for plane in ProfileData.from_file(str(found[-1])).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    spans.setdefault(ev.name, []).append(
+                        (ev.start_ns, ev.start_ns + ev.duration_ns))
+    names = ("repro.flush_round", "repro.assemble", "repro.dispatch",
+             "repro.writeback")
+    for name in names:
+        assert len(spans.get(name, [])) == 1, (name, sorted(spans))
+    (f0, f1), *children = (spans[name][0] for name in names)
+    end = f0
+    for c0, c1 in children:                     # in order, inside the round
+        assert end <= c0 <= c1 <= f1
+        end = c1
+
+
+def test_round_spans_link_to_their_flush_round():
+    svc = _two_stream_service()
+    _enqueue_both(svc)
+    obs.start_tracing()
+    svc.flush_round()
+    obs.stop_tracing()
+    evs = obs.trace_events()
+    (flush,) = [e for e in evs if e["name"] == "flush_round"]
+    assert "parent" not in flush["args"]
+    for name in ("assemble", "dispatch", "writeback"):
+        (child,) = [e for e in evs if e["name"] == name]
+        assert child["args"]["parent"] == flush["args"]["id"], name
+    ids = [e["args"]["id"] for e in evs]
+    assert len(set(ids)) == len(ids)
+
+
+def test_reap_carries_the_round_it_retires(rounds_stay_in_flight):
+    svc = _two_stream_service(max_in_flight=1)
+    obs.start_tracing()
+    _enqueue_both(svc)
+    svc.flush_round()
+    _enqueue_both(svc)
+    svc.flush_round()                           # backpressure retires round 0
+    svc.drain()                                 # the barrier retires round 1
+    obs.stop_tracing()
+    evs = obs.trace_events()
+    rounds = [e["args"]["round"] for e in evs if e["name"] == "flush_round"]
+    reaps = [e["args"]["round"] for e in evs if e["name"] == "reap"]
+    assert rounds == [0, 1]
+    assert reaps == [0, 1]
+
+
+def test_trace_buffer_overflow_counts_dropped_spans(monkeypatch):
+    from repro.obs import trace as obs_trace
+
+    monkeypatch.setattr(obs_trace, "_MAX_EVENTS", 2)
+    obs.start_tracing()
+    for _ in range(5):
+        with obs.span("tick"):
+            pass
+    obs.stop_tracing()
+    assert len(obs.trace_events()) == 2
+    assert obs.registry().get("trace_spans_dropped").value == 3
+
+
+def test_pump_that_seals_nothing_records_no_span(rounds_stay_in_flight):
+    from repro.fleet.frontend import ContinuousBatcher
+
+    svc = _two_stream_service(max_in_flight=1)
+    batcher = ContinuousBatcher(svc, max_depth=1)
+    obs.start_tracing()
+    assert batcher.pump() == 0                  # nothing pending
+    _enqueue_both(svc)
+    assert batcher.pump() == 2                  # seals one round
+    _enqueue_both(svc)
+    assert batcher.pump() == 0                  # pending, but no capacity
+    obs.stop_tracing()
+    pumps = [e for e in obs.trace_events() if e["name"] == "pump"]
+    assert [e["args"]["dispatched"] for e in pumps] == [2]
+
+
+def test_starved_rounds_count_rounds_with_nothing_in_flight(rounds_stay_in_flight):
+    obs.enable()
+    svc = _two_stream_service(max_in_flight=2)
+    _enqueue_both(svc)
+    svc.flush_round()                           # nothing in flight: starved
+    _enqueue_both(svc)
+    svc.flush_round()                           # round 0 still in flight
+    svc.drain()
+    _enqueue_both(svc)
+    svc.flush_round()                           # first after the drain: starved
+    assert svc.stats.flushes == 3
+    assert obs.registry().get("starved_rounds").value == 2
+
+
+def test_queue_wait_counts_every_sealed_event():
+    obs.enable()
+    svc = _two_stream_service()
+    rng = np.random.default_rng(17)
+    for _ in range(3):
+        _enqueue_both(svc)
+    svc.enqueue_op("s0", RankK(jnp.asarray(rng.standard_normal((12, 2))),
+                               jnp.asarray(rng.standard_normal((9, 2)))))
+    svc.register("s2", _state())
+    svc.enqueue("s2", *_event())
+    svc.evict("s2")                             # applied outside a round
+    svc.drain()
+    hist = obs.registry().get("queue_wait_us")
+    # 6 pairs + the op's 2 sealed in rounds; the evicted one was not sealed
+    assert svc.stats.applied == 9
+    assert hist.count == 8
+    assert hist.sum > 0.0
+    assert svc._enqueued_ns == {}               # no stamp outlives its event
+
+
+def test_fleet_enqueue_timers():
+    from repro.fleet.fleet import SvdFleet
+
+    obs.enable()
+    fleet = SvdFleet(1, policy=UpdatePolicy(method="direct"), devices="auto",
+                     max_batch=4)
+    fleet.register("f0", _state())
+    for _ in range(3):
+        fleet.enqueue("f0", *_event())
+    fleet.drain()
+    reg = obs.registry()
+    assert reg.get("enqueue_timed", shard="0").value == 3
+    enqueue_ns = reg.get("enqueue_host_ns", shard="0").value
+    place_ns = reg.get("place_host_ns", shard="0").value
+    assert enqueue_ns >= place_ns > 0
+    assert reg.get("queue_wait_us", shard="0").count == 3
+
+
+def test_disabled_fleet_path_records_nothing():
+    from repro.fleet.fleet import SvdFleet
+
+    fleet = SvdFleet(1, policy=UpdatePolicy(method="direct"), devices="auto",
+                     max_batch=4)
+    for sid in ("f0", "f1"):
+        fleet.register(sid, _state())
+        fleet.enqueue(sid, *_event())
+    fleet.pump()
+    fleet.drain()
+    assert obs.registry().series() == []
+    assert obs.trace_events() == []
+    assert fleet.shards[0].service._enqueued_ns == {}
+    assert obs.span("flush_round", round=0) is obs.span("assemble")
