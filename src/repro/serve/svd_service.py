@@ -73,6 +73,7 @@ The LM engine (``serve.engine``) serves tokens; this serves spectra.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import threading
 import time
@@ -91,9 +92,7 @@ from repro.api.update import engine_from_key, warmup as _api_warmup
 from repro.core.engine import (
     SvdEngine,
     group_indices,
-    stack_trees,
     truncated_geometry,
-    unstack_tree,
 )
 from repro.core.svd_update import TruncatedSvd
 from repro.dist.merge import merge_tree
@@ -352,6 +351,108 @@ def _depth_bucket(run: int, cap: int) -> int:
     while p * 2 <= run:
         p <<= 1
     return p
+
+
+# -- round marshalling ------------------------------------------------------
+#
+# A pair group moves between per-stream arrays and the engine's stacked batch
+# through a few compiled calls each way: done eagerly, a 256 x 8 group is
+# thousands of single-op dispatches on the host.  The executables take at
+# most _MARSHAL_CHUNK streams each, because the TPU compiler's time grows
+# faster than the buffers an executable moves: a whole 256 x 8 group (4,865
+# buffers) compiles for v5e in over a minute, a 32-stream chunk (608) in ~3
+# s.  They are keyed, like the engine's, by batch, depth and geometry, so
+# the warmed set names every executable ``restore`` has to build.
+
+_MARSHAL_CHUNK = 32
+
+# labels of the service whose flush round is running on this thread: a
+# marshalling executable traces inside that call
+_marshal_labels: contextvars.ContextVar = contextvars.ContextVar(
+    "marshal_labels", default={})
+
+
+def _count_marshal_trace(direction: str) -> None:
+    """Count one trace of a marshalling executable (a shape new to this
+    process) in the registry counter ``round_marshal_traces``."""
+    if _obs.enabled():
+        _obs.registry().counter("round_marshal_traces", direction=direction,
+                                **_marshal_labels.get()).inc()
+
+
+@partial(jax.jit, static_argnames="k")
+def _stack_chunk(states, a_vecs, b_vecs, n_valid, *, k: int):
+    """One chunk of ``_assemble_round``; rows from ``n_valid`` on are
+    padding, whose pairs are zeroed."""
+    _count_marshal_trace("assemble")
+    batch = len(states)
+    # one concatenate and a free reshape per stack: jnp.stack's expand_dims
+    # per row traces and lowers about twice as slowly
+    t_stack = TruncatedSvd(*(jnp.concatenate(xs).reshape(batch, *xs[0].shape)
+                             for xs in zip(*states)))
+    dt = t_stack.u.dtype
+    live = (jnp.arange(batch) < n_valid)[:, None, None]
+
+    def pairs(vecs):
+        x = jnp.concatenate(vecs).astype(dt).reshape(batch, k, -1)
+        x = jnp.where(live, x, jnp.zeros((), dt))
+        return x[:, 0] if k == 1 else x
+
+    return t_stack, pairs(a_vecs), pairs(b_vecs)
+
+
+@jax.jit
+def _join_chunks(parts: list):
+    _count_marshal_trace("assemble")
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs), *parts)
+
+
+@jax.jit
+def _cut_chunks(out: TruncatedSvd) -> list:
+    _count_marshal_trace("writeback")
+    return [jax.tree.map(lambda x: x[lo:lo + _MARSHAL_CHUNK], out)
+            for lo in range(0, out.u.shape[0], _MARSHAL_CHUNK)]
+
+
+@jax.jit
+def _unstack_chunk(out: TruncatedSvd) -> list:
+    _count_marshal_trace("writeback")
+    return list(zip(*(jnp.unstack(x) for x in out)))
+
+
+def _assemble_round(states, a_vecs, b_vecs, n_valid: int, *, k: int):
+    """Stack a group's ``(u, s, v)`` states and its pairs (``k`` a stream,
+    stream-major, each stream's in FIFO order) into the engine's batch:
+    a ``TruncatedSvd`` of stacked factors and ``(B, m)`` / ``(B, n)`` pairs
+    at ``k == 1``, ``(B, k, m)`` / ``(B, k, n)`` otherwise, cast to the
+    states' dtype.  Rows from ``n_valid`` on are batch padding: the caller
+    repeats its last state and pairs there, and their pairs are zeroed
+    (no-op updates whose outputs are discarded; scan columns are never
+    padded, see ``_depth_bucket``)."""
+    c = _MARSHAL_CHUNK
+    parts = [_stack_chunk(states[lo:lo + c], a_vecs[lo * k:(lo + c) * k],
+                          b_vecs[lo * k:(lo + c) * k], n_valid - lo, k=k)
+             for lo in range(0, len(states), c)]
+    return parts[0] if len(parts) == 1 else _join_chunks(parts)
+
+
+def _split_round(out: TruncatedSvd) -> list:
+    """The engine's stacked output as one ``(u, s, v)`` per batch row
+    (padding rows too, so the executables stay keyed by the padded batch)."""
+    chunks = [out] if out.u.shape[0] <= _MARSHAL_CHUNK else _cut_chunks(out)
+    return [row for chunk in chunks for row in _unstack_chunk(chunk)]
+
+
+def _warm_marshal(batch: int, m: int, n: int, r: int, k: int, dt) -> None:
+    """Build the marshalling executables of a warmed round shape, by
+    calling them on zeros as a round would call them (pairs in the states'
+    dtype, on the default device).  The zeros are host arrays: eager
+    ``jnp.zeros`` would compile executables of their own."""
+    z = lambda *shape: np.zeros(shape, dt)  # noqa: E731
+    state, a, b = (z(m, r), z(r), z(n, r)), z(m), z(n)
+    t_stack, _, _ = _assemble_round([state] * batch, [a] * (batch * k),
+                                    [b] * (batch * k), batch, k=k)
+    _split_round(t_stack)
 
 
 def _is_ready(x) -> bool:
@@ -908,9 +1009,13 @@ class SvdService:
             return 0
         round_no = self._next_round
         self._next_round += 1
-        with _obs.span("flush_round", streams=len(live_ids),
-                       max_depth=max_depth, round=round_no):
-            applied = self._flush_round_impl(live_ids, max_depth, round_no)
+        labels = _marshal_labels.set(self._obs_labels)
+        try:
+            with _obs.span("flush_round", streams=len(live_ids),
+                           max_depth=max_depth, round=round_no):
+                applied = self._flush_round_impl(live_ids, max_depth, round_no)
+        finally:
+            _marshal_labels.reset(labels)
         if _obs.enabled():
             self._publish_stats()
         return applied
@@ -996,36 +1101,16 @@ class SvdService:
                 # accumulates streams) — never pad negative, just dispatch big
                 pad = max(0, _bucket(bsz, self.max_batch) - bsz)
             with _obs.span("assemble", batch=bsz + pad, depth=k):
-                pairs = [
-                    [(q[j][1], q[j][2]) for j in range(k)]
-                    for q in (self._pending[sid] for sid in sids)
-                ]
                 states = [self._streams[sid] for sid in sids]
-                t_stack = stack_trees(
-                    [TruncatedSvd(s.u, s.s, s.v) for s in states]
+                evs = [q[j] for q in (self._pending[sid] for sid in sids)
+                       for j in range(k)]
+                # batch padding repeats the last stream; the executable
+                # zeroes the padded pairs
+                evs += evs[-k:] * pad
+                t_stack, a_stack, b_stack = _assemble_round(
+                    [(s.u, s.s, s.v) for s in states + states[-1:] * pad],
+                    [ev[1] for ev in evs], [ev[2] for ev in evs], bsz, k=k,
                 )
-                if k == 1:
-                    a_stack = jnp.stack([jnp.asarray(col[0][0], dt) for col in pairs])
-                    b_stack = jnp.stack([jnp.asarray(col[0][1], dt) for col in pairs])
-                    pad_a, pad_b = (pad, m), (pad, n)
-                else:
-                    a_stack = jnp.stack([
-                        jnp.stack([jnp.asarray(a, dt) for a, _ in col]) for col in pairs
-                    ])
-                    b_stack = jnp.stack([
-                        jnp.stack([jnp.asarray(b, dt) for _, b in col]) for col in pairs
-                    ])
-                    pad_a, pad_b = (pad, k, m), (pad, k, n)
-                if pad:
-                    # no-op rank-1 pairs (a = b = 0) along the BATCH axis
-                    # only; padded outputs are discarded (scan columns are
-                    # never padded — their outputs are kept, see _depth_bucket)
-                    t_stack = jax.tree.map(
-                        lambda x: jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)]),
-                        t_stack,
-                    )
-                    a_stack = jnp.concatenate([a_stack, jnp.zeros(pad_a, dt)])
-                    b_stack = jnp.concatenate([b_stack, jnp.zeros(pad_b, dt)])
 
             eng = self._engine_for(r, m, n, dt)
             if self.policy.mesh is None:
@@ -1046,21 +1131,20 @@ class SvdService:
                     self.stats.scan_rounds += 1
                     self.stats.max_depth = max(self.stats.max_depth, k)
             dispatched_ns = time.perf_counter_ns() if self._enqueued_ns else 0
-            if sample_due and probe_args is None and k == 1:
-                st1 = unstack_tree(out, 0)
-                probe_args = (states[0].u, states[0].s, states[0].v,
-                              a_stack[0], b_stack[0], st1.u, st1.s, st1.v)
             with _obs.span("writeback", streams=bsz):
                 first = len(round_tokens)
-                for j, sid in enumerate(sids):
-                    t = unstack_tree(out, j)
-                    self._streams[sid] = SvdState(u=t.u, s=t.s, v=t.v)
+                rows = _split_round(out)
+                for sid, (u, s, v) in zip(sids, rows):
+                    self._streams[sid] = SvdState(u=u, s=s, v=v)
                     for _ in range(k):
                         ev = self._pending[sid].popleft()
                         if ev[-1] is not None:
                             round_tokens.append(ev[-1])
                 if self._enqueued_ns:
                     self._observe_waits(round_tokens[first:], dispatched_ns)
+            if sample_due and probe_args is None and k == 1:
+                probe_args = (states[0].u, states[0].s, states[0].v,
+                              a_stack[0], b_stack[0], *rows[0])
             round_outputs.extend(jax.tree.leaves(out))
             applied += bsz * k
             self.stats.rounds += 1
@@ -1264,6 +1348,10 @@ class SvdService:
                     batch=batch if kind != "trunc" else None,
                     rank=r, k=scan_k, dtype=jnp.dtype(dtype_name),
                 )
+                if kind != "trunc":
+                    # a batched round also runs the marshalling executables
+                    _warm_marshal(batch, m, n, r, scan_k or 1,
+                                  jnp.dtype(dtype_name))
         return svc
 
     @classmethod
