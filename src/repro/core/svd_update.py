@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.eigh_update import apply_update, eigenvalues, make_plan, materialize_q
+from repro.kernels.fused_update import repair_null_columns
 
 __all__ = ["SvdUpdateResult", "TruncatedSvd"]
 
@@ -265,9 +266,13 @@ def _svd_update_truncated_impl(
     eye = jnp.eye(r + 1, dtype=dt)
     res = _svd_update_impl(eye, s_aug, eye, ak, bk, method=method, fmm_p=fmm_p,
                            sign_fix=True, deflate_rtol=deflate_rtol)
+    uk, sk, vk = res.u, res.s, res.v
+    if jnp.dtype(dt).itemsize <= 4:
+        uk, sk, vk = repair_null_columns(uk, sk[None], vk)
+        sk = sk[0]
 
     u_aug = jnp.concatenate([u, p_unit[:, None]], axis=1)   # (m, r+1)
     v_aug = jnp.concatenate([v, q_unit[:, None]], axis=1)   # (n, r+1)
-    u_new = u_aug @ res.u[:, :r]
-    v_new = v_aug @ res.v[:, :r]
-    return TruncatedSvd(u=u_new, s=res.s[:r], v=v_new)
+    u_new = u_aug @ uk[:, :r]
+    v_new = v_aug @ vk[:, :r]
+    return TruncatedSvd(u=u_new, s=sk[:r], v=v_new)

@@ -84,6 +84,8 @@ __all__ = [
     "fused_update_pallas_batched",
     "fused_update_truncated_pallas",
     "fused_update_truncated_pallas_batched",
+    "null_columns",
+    "repair_null_columns",
 ]
 
 
@@ -236,6 +238,66 @@ def _stable_sort_perm(mu_row, mu_col, iota_r, iota_c):
     rank = (jnp.sum(lt, axis=1, keepdims=True, dtype=jnp.int32)
             + jnp.sum(eq, axis=1, keepdims=True, dtype=jnp.int32))   # (k, 1)
     return (rank == iota_c).astype(mu_row.dtype)
+
+
+# ---------------------------------------------------------------------------
+# null columns of a 32-bit truncated core
+# ---------------------------------------------------------------------------
+
+
+def null_columns(s):
+    """Where the singular values ``s`` (along the last axis) are at most
+    ``sqrt(eps) * max(s)``: below that the squared spectrum the chains
+    solve holds no more digits of them, and a 32-bit update cannot take
+    their right vectors from the left ones (nothing to divide by)."""
+    return s <= jnp.sqrt(jnp.finfo(s.dtype).eps) * jnp.max(s, axis=-1, keepdims=True)
+
+
+def _last_to_residual(q, null):
+    """``q`` (k, k) orthonormal with its null columns turned, by one
+    reflection inside their span, so that the last column (where null) is
+    the augmented unit vector ``e_{k-1}`` less its part along the kept
+    columns.  Kept columns are returned exactly."""
+    k = q.shape[1]
+    dt = q.dtype
+    col = _iota((1, k), 1) == k - 1
+    e = (_iota((k, 1), 0) == k - 1).astype(dt)
+    kept = jnp.where(null, jnp.zeros((), dt), q)
+    t = e - _sum(kept * _sum(e * kept, 0), 1)
+    tn2 = _sum(t * t, 0)
+    t = t / jnp.sqrt(jnp.where(tn2 > 0.5, tn2, 1.0))
+    w = _sum(jnp.where(col, q, jnp.zeros((), dt)), 1) - t
+    wn2 = _sum(w * w, 0)
+    # skip it where e_{k-1} lies mostly in the kept span (the residual then
+    # carries a real direction) or the last column is there already
+    last_null = _sum((null & col).astype(dt), 1) > 0.5
+    turn = null & last_null & (tn2 > 0.5) & (wn2 > jnp.finfo(dt).eps)
+    reflected = q - (2.0 / jnp.where(turn, wn2, 1.0)) * w * _sum(w * q, 0)
+    return jnp.where(turn, reflected, q)
+
+
+def repair_null_columns(u, s, v):
+    """The 32-bit SVD ``(u, s, v)`` of a truncated update's (k, k) core
+    (``s`` a (1, k) row), repaired on its null columns
+    (``null_columns(s)``).
+
+    * The chains resolve a singular value through its square, so one below
+      ``sqrt(eps) * s_max`` comes out with an error at least half its size:
+      no digits.  Kept with its arbitrary pair of null vectors, it is a
+      spurious term that the next event takes for data and grows.  Null
+      values are set to zero.
+    * Where an event lies in the state's span to rounding, the Brand
+      residual direction (the core's last coordinate) is rounding noise,
+      not orthogonal to the state's basis.  The null columns of ``u`` and
+      ``v`` are turned inside their span so that the last one, which
+      truncation drops, is that coordinate: the columns kept then carry
+      none of it.
+
+    Kept columns and values are returned exactly.
+    """
+    null = null_columns(s)
+    return (_last_to_residual(u, null), jnp.where(null, jnp.zeros((), s.dtype), s),
+            _last_to_residual(v, null))
 
 
 # ---------------------------------------------------------------------------
@@ -563,10 +625,12 @@ def _fused_body(u, s, v, a, b, *, sign_fix=True, deflate_rtol=None,
         # four ~32s of a Sparse op on a 4096-scale stream).  Take the right
         # vectors from the left ones instead, v_i = (A + a b^T)^T u_i / s_i:
         # paired by construction, sign included.  Below sqrt(eps) * s_max
-        # that division amplifies u's error too far; keep the chain there.
+        # that division amplifies u's error too far; keep the chain there
+        # (the truncated routes then repair those columns:
+        # ``repair_null_columns``).
         au = _mm(uta, g_u)
         w = _mm(v[:, :m], s.T * g_u) + b.T * au     # (A + a b^T)^T u_new
-        ok = s_n > jnp.sqrt(jnp.finfo(cdt).eps) * jnp.max(s_n)
+        ok = ~null_columns(s_n)
         head = jnp.where(ok, w / jnp.where(ok, s_n, 1.0), v_n[:, :m])
         v_n = head if n == m else jnp.concatenate([head, v_n[:, m:]], 1)
 
@@ -614,6 +678,8 @@ def _fused_truncated_body(u, s, v, a, b, *, deflate_rtol=None, n_bisect=28,
         eye, s_aug, eye, ak, bk, sign_fix=True, deflate_rtol=deflate_rtol,
         n_bisect=n_bisect, n_newton=n_newton, compute_dtype=cdt,
     )
+    if cdt.itemsize <= 4:
+        uu, ss, vv = repair_null_columns(uu, ss, vv)
 
     u_aug = jnp.concatenate([uc, p_unit.T], axis=1)
     v_aug = jnp.concatenate([vc, q_unit.T], axis=1)

@@ -96,6 +96,7 @@ from repro.core.engine import (
 )
 from repro.core.svd_update import TruncatedSvd
 from repro.dist.merge import merge_tree
+from repro.kernels.fused_update import null_columns
 from repro.train import checkpoint as _checkpoint
 from repro.updates import ops as _ops
 from repro.updates import planner as _planner
@@ -443,6 +444,17 @@ def _split_round(out: TruncatedSvd) -> list:
     return [row for chunk in chunks for row in _unstack_chunk(chunk)]
 
 
+# bucket bounds of the ``null_columns`` histogram: a count of columns
+_COLUMN_BUCKETS = (0.0, 1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0)
+
+
+@jax.jit
+def _null_counts(s):
+    """Null columns (``kernels.fused_update.null_columns``) of each of a
+    round's stacked states, from its ``s`` output."""
+    return jnp.sum(null_columns(s), axis=-1, dtype=jnp.int32)
+
+
 def _warm_marshal(batch: int, m: int, n: int, r: int, k: int, dt) -> None:
     """Build the marshalling executables of a warmed round shape, by
     calling them on zeros as a round would call them (pairs in the states'
@@ -453,6 +465,7 @@ def _warm_marshal(batch: int, m: int, n: int, r: int, k: int, dt) -> None:
     t_stack, _, _ = _assemble_round([state] * batch, [a] * (batch * k),
                                     [b] * (batch * k), batch, k=k)
     _split_round(t_stack)
+    _null_counts(z(batch, r))
 
 
 def _is_ready(x) -> bool:
@@ -490,8 +503,10 @@ class SvdService:
         # ("pair", a, b, token) | ("op", UpdateOp, token)
         self._pending: dict[str, deque] = {}
         self._eff_shape: dict[str, tuple] = {}   # post-queue (m, n) per stream
-        # per dispatched round: (device outputs, tokens it carried, round number)
-        self._in_flight: deque[tuple[list, list, int]] = deque()
+        # per dispatched round: (device outputs, tokens it carried, round
+        # number, null-column counts (made only while obs is on) and how many
+        # of their rows are streams)
+        self._in_flight: deque[tuple[list, list, int, list]] = deque()
         self._warmed: set[tuple] = set()         # (kind, batch, m, n, r, dtype)
         self._next_token = 0                     # visibility tokens (runtime-only)
         self._next_round = 0                     # round numbers (runtime-only)
@@ -538,10 +553,14 @@ class SvdService:
         return engine_from_key(self.policy, rank + 1, m=m, n=n, rank=rank,
                                dtype=dtype)
 
-    def _record_warm(self, kind: str, batch, m: int, n: int, r: int, dt) -> None:
+    def _record_warm(self, kind: str, batch, m: int, n: int, r: int, dt) -> bool:
         """Track the (kind, geometry) set flushes have compiled — snapshotted
-        so ``restore`` can ``api.warmup`` them eagerly before traffic."""
-        self._warmed.add((kind, batch, m, n, r, jnp.dtype(dt).name))
+        so ``restore`` can ``api.warmup`` them eagerly before traffic.
+        Returns whether the entry is new."""
+        key = (kind, batch, m, n, r, jnp.dtype(dt).name)
+        fresh = key not in self._warmed
+        self._warmed.add(key)
+        return fresh
 
     # -- stream lifecycle ---------------------------------------------------
 
@@ -918,13 +937,26 @@ class SvdService:
         """Retire finished rounds without blocking (oldest-first); their
         tokens become visible."""
         while self._in_flight and all(_is_ready(x) for x in self._in_flight[0][0]):
-            self._visible.extend(self._in_flight.popleft()[1])
+            _, tokens, _, nulls = self._in_flight.popleft()
+            self._retired(tokens, nulls)
 
     def _retire_oldest(self) -> None:
-        outputs, tokens, round_no = self._in_flight.popleft()
+        outputs, tokens, round_no, nulls = self._in_flight.popleft()
         with _obs.span("reap", outputs=len(outputs), round=round_no):
             jax.block_until_ready(outputs)
+        self._retired(tokens, nulls)
+
+    def _retired(self, tokens, nulls) -> None:
+        """A round's outputs are concrete: its tokens become visible, and
+        its null-column counts go into the ``null_columns`` histogram, one
+        observation per (stream, round)."""
         self._visible.extend(tokens)
+        if nulls and _obs.enabled():
+            hist = _obs.registry().histogram("null_columns", bounds=_COLUMN_BUCKETS,
+                                             **self._obs_labels)
+            for counts, n_valid in nulls:
+                for c in np.asarray(counts)[:n_valid].tolist():
+                    hist.observe(c)
 
     # -- observability (repro.obs) ------------------------------------------
 
@@ -1034,6 +1066,7 @@ class SvdService:
         ops_applied = 0    # structured heads (already counted by _apply_event)
         round_outputs: list = []
         round_tokens: list = []
+        round_nulls: list = []
 
         # structured heads: per-stream planner application (geometry may
         # change mid-event, so they cannot share a batch)
@@ -1113,9 +1146,10 @@ class SvdService:
                 )
 
             eng = self._engine_for(r, m, n, dt)
+            fresh = False
             if self.policy.mesh is None:
                 kind = "trunc_batch" if k == 1 else f"trunc_scan{k}"
-                self._record_warm(kind, bsz + pad, m, n, r, dt)
+                fresh = self._record_warm(kind, bsz + pad, m, n, r, dt)
             with _obs.span("dispatch", m=m, n=n, rank=r, batch=bsz + pad,
                            depth=k):
                 if k == 1:
@@ -1146,15 +1180,24 @@ class SvdService:
                 probe_args = (states[0].u, states[0].s, states[0].v,
                               a_stack[0], b_stack[0], *rows[0])
             round_outputs.extend(jax.tree.leaves(out))
+            if _obs.enabled() or fresh:
+                # built with the round shape's other executables, so a
+                # window traced later builds nothing; kept only while obs
+                # is on, and read once the round is concrete
+                nulls = _null_counts(out.s)
+                if _obs.enabled():
+                    round_nulls.append((nulls, bsz))
+                    round_outputs.append(nulls)
             applied += bsz * k
             self.stats.rounds += 1
             self.stats.max_batch = max(self.stats.max_batch, bsz + pad)
 
         if self.max_in_flight == 0:
             jax.block_until_ready(round_outputs)       # synchronous mode
-            self._visible.extend(round_tokens)
+            self._retired(round_tokens, round_nulls)
         else:
-            self._in_flight.append((round_outputs, round_tokens, round_no))
+            self._in_flight.append((round_outputs, round_tokens, round_no,
+                                    round_nulls))
             self.stats.in_flight_peak = max(
                 self.stats.in_flight_peak, len(self._in_flight)
             )
